@@ -90,9 +90,11 @@ class SelSpec(NamedTuple):
 
 
 @contextlib.contextmanager
-def use_kernels(enabled: bool = True):
-    """Route the compact dW computation through the Pallas kernel."""
-    prev = getattr(_FLAGS, "kernels", False)
+def use_kernels(enabled: Optional[bool] = True):
+    """Route the compact dW, optimizer and writeback through the Pallas
+    kernels (True) or the jnp oracle (False). None restores the default:
+    kernels exactly when the backend is a TPU. Read at trace time."""
+    prev = getattr(_FLAGS, "kernels", None)
     _FLAGS.kernels = enabled
     try:
         yield
@@ -101,7 +103,8 @@ def use_kernels(enabled: bool = True):
 
 
 def kernels_enabled() -> bool:
-    return getattr(_FLAGS, "kernels", False)
+    flag = getattr(_FLAGS, "kernels", None)
+    return jax.default_backend() == "tpu" if flag is None else flag
 
 
 @contextlib.contextmanager
@@ -308,6 +311,9 @@ def smm(x, w, sel, name: str):
     if idx_dict is None or name not in idx_dict:
         return jnp.matmul(x, w)
     idx, spec = idx_dict[name], spec_dict[name]
+    if kernels_enabled():
+        from repro.kernels import ops as kops
+        kops.check_tpu_tiling(name, w.shape, spec)
     wsel_dict = sel[2] if len(sel) > 2 else None
     if wsel_dict is not None and name in wsel_dict:
         if w.ndim == 2:

@@ -15,6 +15,10 @@ shared across experts: the framework selects per weight, not per expert).
     idx: [n_shards, n_sel]  selected block indices, local to each shard
     out: [E, K, n_shards, n_sel, block]   compact dW (fp32)
 
+As in `masked_dw`, the kernel writes the flat view
+[E, K, n_shards * n_sel * block] with a (TK, block) out block per expert,
+so the trailing block dims are tiles the TPU compiler accepts.
+
 Grid: (E, n_shards, n_sel, K/TK, C/TM); C is the contraction ("arbitrary")
 innermost dimension, accumulated into a VMEM scratch across grid steps.
 Unselected blocks are never read, computed, or written.
@@ -36,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import ensure_pipeline_emulation, pallas_compiler_params
+from repro.kernels.masked_dw import interpret_pipeline
 
 
 def _kernel(idx_ref, x_ref, dy_ref, out_ref, acc_ref, *, n_m: int):
@@ -54,7 +58,7 @@ def _kernel(idx_ref, x_ref, dy_ref, out_ref, acc_ref, *, n_m: int):
 
     @pl.when(mi == n_m - 1)
     def _flush():
-        out_ref[...] = acc_ref[...][None, :, None, None, :]
+        out_ref[...] = acc_ref[...]
 
 
 def batched_dw_kernel(x, dy, idx, *, block: int, tm: int = 128,
@@ -87,18 +91,20 @@ def batched_dw_kernel(x, dy, idx, *, block: int, tm: int = 128,
                              (ei, mi, si * n_blocks + idx_ref[si, ji])),
             ],
             out_specs=pl.BlockSpec(
-                (1, tk, 1, 1, block),
-                lambda ei, si, ji, ki, mi, idx_ref: (ei, ki, si, ji, 0)),
+                (None, tk, block),
+                lambda ei, si, ji, ki, mi, idx_ref:
+                (ei, ki, si * n_sel + ji)),
             scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((e, k, n_shards, n_sel, block),
+        out_shape=jax.ShapeDtypeStruct((e, k, n_shards * n_sel * block),
                                        jnp.float32),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
+        name="batched_dw",
     )(idx, x, dy)
-    return out
+    return out.reshape(e, k, n_shards, n_sel, block)
 
 
 def _pipelined_kernel(idx_ref, x_hbm, dy_hbm, out_ref, acc_ref, *,
@@ -124,7 +130,7 @@ def _pipelined_kernel(idx_ref, x_hbm, dy_hbm, out_ref, acc_ref, *,
         ],
         out_specs=(),
     )(x_hbm, dy_hbm)
-    out_ref[...] = acc_ref[...][None, :, None, None, :]
+    out_ref[...] = acc_ref[...]
 
 
 def batched_dw_pipelined_kernel(x, dy, idx, *, block: int, tm: int = 128,
@@ -132,7 +138,6 @@ def batched_dw_pipelined_kernel(x, dy, idx, *, block: int, tm: int = 128,
     """Double-buffered `batched_dw_kernel`: same contract, but x/dy live in
     HBM and an inner `emit_pipeline` streams the C-tiles — VMEM holds two
     in-flight tiles per operand regardless of C."""
-    ensure_pipeline_emulation()
     e, m, k = x.shape
     n = dy.shape[-1]
     n_shards, n_sel = idx.shape
@@ -144,26 +149,29 @@ def batched_dw_pipelined_kernel(x, dy, idx, *, block: int, tm: int = 128,
     n_m = m // tm
 
     grid = (e, n_shards, n_sel, k // tk)
-    out = pl.pallas_call(
-        functools.partial(_pipelined_kernel, tm=tm, tk=tk, block=block,
-                          n_m=n_m, n_blocks=n_blocks),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, tk, 1, 1, block),
-                lambda ei, si, ji, ki, idx_ref: (ei, ki, si, ji, 0)),
-            scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((e, k, n_shards, n_sel, block),
-                                       jnp.float32),
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "parallel")),
-        interpret=interpret,
-    )(idx, x, dy)
-    return out
+    with interpret_pipeline(interpret):
+        out = pl.pallas_call(
+            functools.partial(_pipelined_kernel, tm=tm, tk=tk, block=block,
+                              n_m=n_m, n_blocks=n_blocks),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, tk, block),
+                    lambda ei, si, ji, ki, idx_ref:
+                    (ei, ki, si * n_sel + ji)),
+                scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((e, k, n_shards * n_sel * block),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "parallel")),
+            interpret=interpret,
+            name="batched_dw_pipelined",
+        )(idx, x, dy)
+    return out.reshape(e, k, n_shards, n_sel, block)

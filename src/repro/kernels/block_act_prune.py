@@ -37,4 +37,5 @@ def block_act_prune_kernel(x, *, threshold: float = 0.15, block: int = 2,
         out_specs=pl.BlockSpec((tr, tc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
         interpret=interpret,
+        name="block_act_prune",
     )(x)

@@ -14,8 +14,10 @@ the same pass, touching n_sel/n_blocks of each tensor.
     idx: [K, n_shards, n_sel]            selected block indices, shard-local
     mu:  [K, R, N] fp32                  first moment (momentum/adamw) or None
     nu:  [K, R, N] fp32                  second moment (adamw) or None
-    lr, t: traced fp32 scalars (learning rate, adamw bias-correction step),
-           scalar-prefetched alongside idx.
+    lr, t: traced fp32 scalars (learning rate, adamw bias-correction step);
+           lr and AdamW's bias corrections 1 - beta**t are computed outside
+           the kernel (Mosaic has no scalar pow) and scalar-prefetched
+           alongside idx.
 
 Returns (w', mu', nu') with None for absent state; every input tensor is
 aliased to its output, so unselected blocks are never read or written.
@@ -24,6 +26,10 @@ The per-tile arithmetic mirrors `repro.optim.optimizers._leaf_update`
 exactly (fp32 compute, cast back to the param dtype): SGD is bitwise
 identical to the jnp gather/update/scatter oracle; momentum/AdamW are
 allclose (elementwise, so in practice also bitwise).
+
+The compact gradient is read through its row-major flat view
+[K, R, n_shards * n_sel * block] (column block `s * n_sel + j`), so every
+block's trailing dims are tiles the TPU compiler accepts.
 
 Grid: (K, n_shards, n_sel, R/TR); selection dims are "arbitrary"
 (sequential) so a duplicate index cannot race — selection never produces
@@ -38,11 +44,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
-
-def _rule(kind: str, hp: dict, lr, t, p, g, mu, nu):
-    """The optimizer block rule on fp32 tiles; mirrors _leaf_update."""
+def _rule(kind: str, hp: dict, lr, bc1, bc2, p, g, mu, nu):
+    """The optimizer block rule on fp32 tiles; mirrors _leaf_update, with
+    the AdamW bias corrections bc = 1 - beta**t passed in."""
     if kind == "sgd":
         new = p - lr * g
         if hp["weight_decay"]:
@@ -58,8 +63,8 @@ def _rule(kind: str, hp: dict, lr, t, p, g, mu, nu):
         b1, b2 = hp["beta1"], hp["beta2"]
         mu_new = b1 * mu + (1 - b1) * g
         nu_new = b2 * nu + (1 - b2) * g * g
-        mu_hat = mu_new / (1 - b1 ** t)
-        nu_hat = nu_new / (1 - b2 ** t)
+        mu_hat = mu_new / bc1
+        nu_hat = nu_new / bc2
         new = p - lr * (mu_hat / (jnp.sqrt(nu_hat) + hp["eps"])
                         + hp["weight_decay"] * p)
         return new, mu_new, nu_new
@@ -68,16 +73,15 @@ def _rule(kind: str, hp: dict, lr, t, p, g, mu, nu):
 
 def _kernel(idx_ref, hyper_ref, *refs, kind: str, hp: dict):
     del idx_ref
-    lr = hyper_ref[0]
-    t = hyper_ref[1]
+    lr, bc1, bc2 = hyper_ref[0], hyper_ref[1], hyper_ref[2]
     n_state = {"sgd": 0, "momentum": 1, "adamw": 2}[kind]
     ins, outs = refs[: 2 + n_state], refs[2 + n_state:]
     w_ref, g_ref = ins[0], ins[1]
     p = w_ref[0].astype(jnp.float32)                 # [TR, block]
-    g = g_ref[0, :, 0, 0, :].astype(jnp.float32)
+    g = g_ref[0].astype(jnp.float32)
     mu = ins[2][0] if n_state >= 1 else None         # fp32 already
     nu = ins[3][0] if n_state >= 2 else None
-    new, mu_new, nu_new = _rule(kind, hp, lr, t, p, g, mu, nu)
+    new, mu_new, nu_new = _rule(kind, hp, lr, bc1, bc2, p, g, mu, nu)
     outs[0][...] = new.astype(outs[0].dtype)[None]
     if n_state >= 1:
         outs[1][...] = mu_new[None]
@@ -106,17 +110,20 @@ def fused_block_opt_kernel(w, g, idx, lr, t, mu=None, nu=None, *, kind: str,
     assert (mu is not None) == (n_state >= 1)
     assert (nu is not None) == (n_state >= 2)
 
+    t = jnp.asarray(t, jnp.float32)
     hyper = jnp.stack([jnp.asarray(lr, jnp.float32),
-                       jnp.asarray(t, jnp.float32)])
+                       1 - beta1 ** t, 1 - beta2 ** t])
     full_spec = pl.BlockSpec(
         (1, tr, block),
         lambda kk, si, ji, ri, idx_ref, hyper_ref:
         (kk, ri, si * n_blocks + idx_ref[kk, si, ji]))
     g_spec = pl.BlockSpec(
-        (1, tr, 1, 1, block),
-        lambda kk, si, ji, ri, idx_ref, hyper_ref: (kk, ri, si, ji, 0))
+        (1, tr, block),
+        lambda kk, si, ji, ri, idx_ref, hyper_ref:
+        (kk, ri, si * n_sel + ji))
 
-    operands = [w, g] + [s for s in (mu, nu) if s is not None]
+    g_flat = g.reshape(k, r, n_shards * n_sel * block)
+    operands = [w, g_flat] + [s for s in (mu, nu) if s is not None]
     in_specs = [full_spec, g_spec] + [full_spec] * n_state
     out_specs = [full_spec] * (1 + n_state)
     out_shape = [jax.ShapeDtypeStruct((k, r, n), w.dtype)] \
@@ -142,10 +149,11 @@ def fused_block_opt_kernel(w, g, idx, lr, t, mu=None, nu=None, *, kind: str,
         ),
         out_shape=tuple(out_shape),
         input_output_aliases=aliases,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "parallel")),
         interpret=interpret,
+        name="fused_block_opt",
     )(idx, hyper, *operands)
     w_new = out[0]
     mu_new = out[1] if n_state >= 1 else None

@@ -9,15 +9,17 @@ of the paper's skipped gradient loops).
 
 ONE `pallas_call` covers every TP shard: the grid spans shards as well as
 selected blocks, and the scalar-prefetched [n_shards, n_sel] index table
-routes the dY BlockSpec to `shard_base + idx[s, j]`. The output is emitted
-directly in the framework's compact layout — no Python shard loop, no
-post-hoc stack/transpose in `ops.py` (PR 1 launched one kernel per shard
-and reassembled on the host side of the trace).
+routes the dY BlockSpec to `shard_base + idx[s, j]`. No Python shard loop.
 
     x:   [M, K]            activations (fan-in K)
     dy:  [M, N]            upstream gradient (N = n_shards * n_blocks * block)
     idx: [n_shards, n_sel] selected block indices, local to each shard
     out: [K, n_shards, n_sel, block]   compact dW (fp32)
+
+The kernel writes the row-major flat view [K, n_shards * n_sel * block] of
+that layout with a (TK, block) out block at column block `s * n_sel + j`,
+so both trailing block dims are tiles the TPU compiler accepts (multiples
+of (8, 128)); the reshape back to the compact layout is free.
 
 Grid: (n_shards, n_sel, K/TK, M/TM); M is the contraction ("arbitrary")
 innermost dimension, accumulated into a VMEM scratch across grid steps.
@@ -34,14 +36,13 @@ selects it when a whole contraction stripe stops fitting VMEM.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import ensure_pipeline_emulation, pallas_compiler_params
 
 
 def _kernel(idx_ref, x_ref, dy_ref, out_ref, acc_ref, *, n_m: int):
@@ -59,7 +60,7 @@ def _kernel(idx_ref, x_ref, dy_ref, out_ref, acc_ref, *, n_m: int):
 
     @pl.when(mi == n_m - 1)
     def _flush():
-        out_ref[...] = acc_ref[...][:, None, None, :]
+        out_ref[...] = acc_ref[...]
 
 
 def block_sparse_dw_kernel(x, dy, idx, *, block: int, tm: int = 128,
@@ -89,18 +90,40 @@ def block_sparse_dw_kernel(x, dy, idx, *, block: int, tm: int = 128,
                              (mi, si * n_blocks + idx_ref[si, ji])),
             ],
             out_specs=pl.BlockSpec(
-                (tk, 1, 1, block),
-                lambda si, ji, ki, mi, idx_ref: (ki, si, ji, 0)),
+                (tk, block),
+                lambda si, ji, ki, mi, idx_ref: (ki, si * n_sel + ji)),
             scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((k, n_shards, n_sel, block),
+        out_shape=jax.ShapeDtypeStruct((k, n_shards * n_sel * block),
                                        jnp.float32),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="masked_dw",
     )(idx, x, dy)
-    return out
+    return out.reshape(k, n_shards, n_sel, block)
+
+
+@contextlib.contextmanager
+def interpret_pipeline(interpret: bool):
+    """Let `pltpu.emit_pipeline` trace in interpret mode off-TPU.
+
+    The pipeline helper sizes its DMA-slice tiling from the device's TPU
+    generation, which a CPU device does not have. That tiling only truncates
+    out-of-bounds edge blocks, and these kernels require tile-divisible
+    shapes, so an interpret-mode trace pins a v4-class answer. Compiled
+    calls (interpret=False) keep the real lookup."""
+    if not interpret:
+        yield
+        return
+    from jax._src.pallas.mosaic import pipeline
+    real = pipeline._get_tpu_generation
+    pipeline._get_tpu_generation = lambda: 4
+    try:
+        yield
+    finally:
+        pipeline._get_tpu_generation = real
 
 
 def _pipelined_kernel(idx_ref, x_hbm, dy_hbm, out_ref, acc_ref, *,
@@ -125,14 +148,13 @@ def _pipelined_kernel(idx_ref, x_hbm, dy_hbm, out_ref, acc_ref, *,
         ],
         out_specs=(),
     )(x_hbm, dy_hbm)
-    out_ref[...] = acc_ref[...][:, None, None, :]
+    out_ref[...] = acc_ref[...]
 
 
 def block_sparse_dw_pipelined_kernel(x, dy, idx, *, block: int, tm: int = 128,
                                      tk: int = 128, interpret: bool = False):
     """Double-buffered `block_sparse_dw_kernel`: same contract, but x/dy
     live in HBM and an inner `emit_pipeline` streams the M tiles."""
-    ensure_pipeline_emulation()
     m, k = x.shape
     n = dy.shape[1]
     n_shards, n_sel = idx.shape
@@ -143,25 +165,27 @@ def block_sparse_dw_pipelined_kernel(x, dy, idx, *, block: int, tm: int = 128,
     n_m = m // tm
 
     grid = (n_shards, n_sel, k // tk)
-    out = pl.pallas_call(
-        functools.partial(_pipelined_kernel, tm=tm, tk=tk, block=block,
-                          n_m=n_m, n_blocks=n_blocks),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            ],
-            out_specs=pl.BlockSpec(
-                (tk, 1, 1, block),
-                lambda si, ji, ki, idx_ref: (ki, si, ji, 0)),
-            scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((k, n_shards, n_sel, block),
-                                       jnp.float32),
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(idx, x, dy)
-    return out
+    with interpret_pipeline(interpret):
+        out = pl.pallas_call(
+            functools.partial(_pipelined_kernel, tm=tm, tk=tk, block=block,
+                              n_m=n_m, n_blocks=n_blocks),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                    pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                ],
+                out_specs=pl.BlockSpec(
+                    (tk, block),
+                    lambda si, ji, ki, idx_ref: (ki, si * n_sel + ji)),
+                scratch_shapes=[pltpu.VMEM((tk, block), jnp.float32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((k, n_shards * n_sel * block),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=interpret,
+            name="masked_dw_pipelined",
+        )(idx, x, dy)
+    return out.reshape(k, n_shards, n_sel, block)

@@ -1,8 +1,9 @@
 """Jit'd wrappers integrating the Pallas kernels with the framework.
 
-On CPU (no TPU backend) the kernels run in interpret mode — the Pallas body
-executes exactly as it would be staged for TPU, validating index maps and
-block logic. On TPU the same call compiles to Mosaic.
+On a TPU backend every call compiles to Mosaic. Off TPU (the CPU test
+suite) the kernels run in interpret mode — the Pallas body executes as it
+would be staged for TPU, validating index maps and block logic, but not
+the chip's tiling rules: `check_tpu_tiling` enforces those up front.
 
 Every logical op here is ONE `pallas_call`: the dW, writeback, and fused
 optimizer kernels take the whole stacked leaf (all trainable scan-steps and
@@ -26,6 +27,33 @@ from repro.kernels.masked_dw import (block_sparse_dw_kernel,
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+# Mosaic takes a block whose last dim is a multiple of the vreg's 128 lanes
+# or the whole array dim
+LANES = 128
+
+
+def check_tpu_tiling(leaf: str, w_shape, spec) -> None:
+    """On a TPU backend, raise a readable error for a selectable leaf whose
+    compact-path kernels Mosaic would refuse: the channel block is the lane
+    dim of every dW / optimizer / writeback block, and the fan-in tile is
+    the lane dim of the dW kernels' activation block."""
+    if jax.default_backend() != "tpu":
+        return
+    k, n = w_shape[-2], w_shape[-1]
+    if spec.block % LANES:
+        raise ValueError(
+            f"leaf {leaf!r} (out width {n}): channel block {spec.block} is "
+            f"not a multiple of the TPU's {LANES} lanes; use a channel block "
+            f"of {LANES} (a width with no such divisor cannot take the "
+            f"compact-path kernels)")
+    tk = _pick_tile(k, LANES)
+    if tk != k and tk % LANES:
+        raise ValueError(
+            f"leaf {leaf!r}: fan-in {k} has no {LANES}-lane tile (picked "
+            f"{tk}); the dW kernels need a fan-in that is a multiple of "
+            f"{LANES} or at most {LANES}")
 
 
 def _pick_tile(r: int, cap: int = 256) -> int:

@@ -20,6 +20,11 @@ separate 2D kernels from a Python loop), with the scalar-prefetched
     idx: [K, n_shards, n_sel]          selected block indices, shard-local
     out: [K, R, N]                     w with the selected blocks overwritten
 
+The kernel reads `upd` through its row-major flat view
+[K, R, n_shards * n_sel * block], so each (TR, block) tile sits at column
+block `s * n_sel + j` and both trailing block dims are tiles the TPU
+compiler accepts.
+
 Grid: (K, n_shards, n_sel, R/TR). If idx contains duplicates within a
 (k, shard) the highest grid step wins (the sel dims are "arbitrary", i.e.
 sequential) — selection never produces duplicates within a shard.
@@ -31,12 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 
 def _kernel(idx_ref, w_ref, upd_ref, out_ref):
     del idx_ref, w_ref
-    out_ref[...] = upd_ref[:, :, 0, 0, :].astype(out_ref.dtype)
+    out_ref[...] = upd_ref[...].astype(out_ref.dtype)
 
 
 def block_scatter_update_kernel(w, upd, idx, *, tr: int = 256,
@@ -64,16 +67,17 @@ def block_scatter_update_kernel(w, upd, idx, *, tr: int = 256,
             grid=grid,
             in_specs=[
                 out_spec,
-                pl.BlockSpec((1, tr, 1, 1, block),
+                pl.BlockSpec((1, tr, block),
                              lambda kk, si, ji, ri, idx_ref:
-                             (kk, ri, si, ji, 0)),
+                             (kk, ri, si * n_sel + ji)),
             ],
             out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((k, r, n), w.dtype),
         input_output_aliases={1: 0},   # w aliases out: unselected blocks kept
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "parallel")),
         interpret=interpret,
-    )(idx, w, upd)
+        name="scatter_blocks",
+    )(idx, w, upd.reshape(k, r, n_shards * n_sel * block))

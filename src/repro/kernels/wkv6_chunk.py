@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, chunk: int):
     ti = pl.program_id(1)
@@ -99,7 +97,8 @@ def wkv6_chunk_kernel(r, k, v, w, u, *, chunk: int = 32,
         out_specs=pl.BlockSpec((1, chunk, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="wkv6_chunk",
     )(r, k, v, w, u2)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from repro.configs import (ARCH_IDS, SHAPES, SparseUpdateConfig, cell_is_skipped,
                            get_config)
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.launch.specs import (input_specs, make_decode_cell, make_prefill_cell,
                                 make_train_cell, rules_for)
 from repro.sharding import use_rules
@@ -66,8 +66,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     t0 = time.time()
     if mesh_shape is not None:
-        from repro.compat import make_mesh
-        mesh = make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_debug_mesh(*mesh_shape)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     rules = rules_for(mesh, cfg, shape)
@@ -108,8 +107,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
         mem = compiled.memory_analysis()
         rec["memory"] = _mem_dict(mem)
-        from repro.compat import cost_analysis
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         rec["hlo_flops_body_once"] = float(cost.get("flops", 0.0))
         rec["hlo_bytes_body_once"] = float(cost.get("bytes accessed", 0.0))
         txt = compiled.as_text()

@@ -370,7 +370,7 @@ _KERNEL_CALL_RE = re.compile(
 
 
 def _iter_sub_jaxprs(val):
-    import jax.core as jc
+    import jax.extend.core as jc
     if isinstance(val, jc.ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, jc.Jaxpr):
@@ -400,32 +400,20 @@ def kernel_launch_count(obj) -> int:
 def kernel_launch_breakdown(obj) -> dict[str, int]:
     """`kernel_launch_count` split by kernel name (jaxpr path only).
 
-    Walks the same recursion as `_count_pallas_eqns` but keys each
-    `pallas_call` site by its kernel function name (`eqn.params["name"]`),
-    so a test can certify the per-KERNEL launch budget of a lowered train
-    step — e.g. the MoE compact step must show exactly one `batched_dw`
-    site and one fused-optimizer site per expert-sharded leaf, independent
-    of n_experts / K / n_shards."""
+    Walks the same recursion as `kernel_launch_count` and keys each
+    `pallas_call` site by the stable `name=` its kernel module passes
+    (`masked_dw`, `batched_dw`, `fused_block_opt`, ...), so a test can
+    certify the per-KERNEL launch budget of a lowered train step — e.g.
+    the MoE compact step must show exactly one `batched_dw` site and one
+    fused-optimizer site per expert-sharded leaf, independent of
+    n_experts / K / n_shards."""
     jaxpr = getattr(obj, "jaxpr", obj)      # ClosedJaxpr -> Jaxpr
     out: dict[str, int] = {}
-
-    def site_name(params) -> str:
-        # "name_and_src_info" renders as "<fn> at <file>:<line>"; the kernel
-        # fns are private `_kernel`s, so key by their defining module stem.
-        info = str(params.get("name_and_src_info",
-                              params.get("name", "")) or "pallas_call")
-        fn = info.split(" at ")[0]
-        if " at " in info:
-            path = info.split(" at ")[1].rsplit(":", 1)[0]
-            stem = path.replace("\\", "/").rsplit("/", 1)[-1]
-            stem = stem[:-3] if stem.endswith(".py") else stem
-            return f"{stem}.{fn}"
-        return fn
 
     def walk(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                name = site_name(eqn.params)
+                name = eqn.params.get("name") or "pallas_call"
                 out[name] = out.get(name, 0) + 1
                 continue
             for val in eqn.params.values():

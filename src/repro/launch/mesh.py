@@ -3,7 +3,12 @@ module never touches jax device state (device count is locked at first
 jax init, and only the dry-run forces 512 host devices)."""
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,21 +18,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading pod axis (DP across DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many (host) devices exist — tests."""
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_serve_mesh(n_model: int = 1):
     """(1, n_model) serve mesh over the FIRST n_model local devices.
 
-    Unlike `make_mesh` (which lays out every device), serving wants exactly
+    Unlike `jax.make_mesh` (which lays out every device), serving wants exactly
     the shard count asked for — e.g. 4 pool shards on an 8-device host —
     so the mesh is built from an explicit device subset."""
-    import jax
     import numpy as np
     from jax.sharding import Mesh
 

@@ -9,17 +9,22 @@ prompts chunk-prefill a page at a time (long admissions never stall
 in-flight decodes), common prompt prefixes share refcounted pages
 copy-on-write, and `--stream` prints tokens as they are sampled. Reported
 request/token counts cover COMPLETED requests only — padded slots and
-cancelled/timed-out requests are never counted. On CPU this serves the
-smoke configs; the same engine lowers to the production mesh for the full
-configs (see launch/dryrun.py decode cells).
+cancelled/timed-out requests are never counted. On the CPU this serves the
+smoke configs; on a TPU the full configs, on one chip or, with
+`--mesh-model N`, tensor-parallel over N chips. Parameters are built
+straight into their serving layout, so no chip ever holds a whole
+unsharded copy of a model that needs the mesh.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import decoding as D
 from repro.models import transformer as T
 from repro.runtime.chaos import FaultSchedule
 from repro.serve import PersonalizationConfig, ServeEngine
@@ -28,18 +33,35 @@ from repro.serve.engine import (make_branching_prefix_requests,
                                 make_shared_prefix_requests)
 
 
+def init_serve_params(cfg, key, rules=None):
+    """Random parameters, initialized in one jitted program whose outputs
+    land directly in the serving layout: sharded per `paged_param_specs`
+    when `rules` carry a mesh, on the default device otherwise."""
+    init = functools.partial(T.init_params, cfg)
+    if rules is None:
+        return jax.jit(init)(key)
+    from repro.sharding import spec_tree_to_shardings
+    shardings = spec_tree_to_shardings(rules.mesh, D.paged_param_specs(
+        cfg, jax.eval_shape(init, key), rules))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
 def build_engine(args, cfg=None):
     cfg = cfg or (get_smoke_config(args.arch) if args.smoke
                   else get_config(args.arch))
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
+    rules = None
+    if getattr(args, "mesh_model", 1) > 1:
+        from repro.launch.mesh import make_serve_mesh
+        from repro.sharding import default_rules
+        rules = default_rules(make_serve_mesh(args.mesh_model))
+    params = init_serve_params(cfg, jax.random.PRNGKey(args.seed), rules)
     p13n = None
     if args.users > 0:
         from repro.configs.base import OptimizerConfig, SparseUpdateConfig
         p13n = PersonalizationConfig(
             sparse=SparseUpdateConfig(
                 update_ratio=args.personalize_ratio,
-                num_update_layers=args.personalize_layers,
-                channel_block=8),
+                num_update_layers=args.personalize_layers),
             optimizer=OptimizerConfig(kind="sgd",
                                       learning_rate=args.personalize_lr),
             store_capacity=args.delta_capacity,
@@ -49,11 +71,6 @@ def build_engine(args, cfg=None):
         chaos = FaultSchedule(args.chaos_seed, fault_rate=args.fault_rate,
                               slow_s=args.chaos_slow_s,
                               kill_after=args.kill_after)
-    rules = None
-    if getattr(args, "mesh_model", 1) > 1:
-        from repro.launch.mesh import make_serve_mesh
-        from repro.sharding import default_rules
-        rules = default_rules(make_serve_mesh(args.mesh_model))
     flash_decode = True if getattr(args, "flash_decode", False) else None
     engine = ServeEngine(
         cfg, params, num_slots=args.batch,
@@ -182,6 +199,7 @@ def add_serve_args(ap: argparse.ArgumentParser):
 
 def main(argv=None):
     args = add_serve_args(argparse.ArgumentParser()).parse_args(argv)
+    enable_compile_cache()
     cfg, engine = build_engine(args)
     stats = engine.run(build_requests(args, cfg), verbose=not args.stream)
     print(f"[serve] {stats.requests_completed}/{args.requests} requests "

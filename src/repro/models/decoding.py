@@ -681,7 +681,6 @@ def make_sharded_paged_step(cfg, rules, params, *, page_size: int,
     single device. `params` is only used for its tree structure/shapes
     (in_specs are a full pytree over the param leaves)."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     mesh, axis = rules.mesh, rules.model_axis
     validate_pool_sharding(cfg, rules)
@@ -697,7 +696,7 @@ def make_sharded_paged_step(cfg, rules, params, *, page_size: int,
                               page_size=page_size, deltas=deltas,
                               flash_decode=flash_decode)
 
-    base = jax.jit(shard_map(
+    base = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, P(), P(), pool_pspec(rules), P()),
         **io_specs))
@@ -709,7 +708,7 @@ def make_sharded_paged_step(cfg, rules, params, *, page_size: int,
         key = jax.tree.structure(deltas)
         step = delta_steps.get(key)
         if step is None:
-            step = jax.jit(shard_map(
+            step = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(param_specs, P(), P(), pool_pspec(rules), P(),
                           jax.tree.map(lambda _: P(), deltas)),

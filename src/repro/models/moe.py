@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.sparse_update import smm
 from repro.models.common import dense_init
 from repro.sharding import current_mapped_axis, current_rules, psum_mapped
@@ -145,7 +144,7 @@ def apply_moe(p, cfg, x, sel=None):
                 return _dispatch_combine(
                     cfg, xf, i, w, {"w_gate": wg, "w_up": wu, "w_down": wd},
                     sel, axis, e_local, capacity)
-        y_flat = shard_map(
+        y_flat = jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=P(rules.rules.get("batch"), None),

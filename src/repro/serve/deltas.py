@@ -46,8 +46,7 @@ class PersonalizationConfig:
     """
 
     def __init__(self, sparse=None, optimizer=None, *, store_capacity=32,
-                 train_tokens: int = 16, use_kernels: bool = False,
-                 seed: int = 0):
+                 train_tokens: int = 16, seed: int = 0):
         from repro.configs.base import OptimizerConfig, SparseUpdateConfig
         self.sparse = sparse or SparseUpdateConfig(
             update_ratio=0.25, num_update_layers=2, channel_block=8)
@@ -55,7 +54,6 @@ class PersonalizationConfig:
             kind="sgd", learning_rate=0.05)
         self.store_capacity = int(store_capacity)
         self.train_tokens = int(train_tokens)
-        self.use_kernels = bool(use_kernels)
         self.seed = int(seed)
 
 

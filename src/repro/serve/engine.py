@@ -319,12 +319,10 @@ class ServeEngine:
             else rules is not None
 
         ps = page_size
-        # personalization trains its waves on the ORIGINAL replicated params
-        # (on a mesh, self.params becomes a sharded copy below): waves must
-        # be bit-identical to a single-device engine's, or the deltas — and
-        # therefore the served tokens — would diverge across mesh sizes
-        self._host_params = params
         if rules is not None:
+            # a no-op for params already built in this layout
+            # (`launch.serve.init_serve_params`); the engine keeps no
+            # other copy unless personalization needs one (below)
             from repro.sharding import spec_tree_to_shardings
             self.params = params = jax.device_put(
                 params, spec_tree_to_shardings(
@@ -384,7 +382,14 @@ class ServeEngine:
             "personalization trains on token streams; embed-input frontends "
             "have none")
         plan = build_plan(self.cfg, p.sparse, 0)
-        frozen, trainable = split_params(self._host_params, plan)
+        # waves train on one whole copy of the params on the mesh's first
+        # device: they must be bit-identical to a single-device engine's,
+        # or the deltas — and so the served tokens — would diverge across
+        # mesh sizes. That copy is the one full model a mesh engine holds
+        # on one chip, so personalization needs a model that fits a chip.
+        base = self.params if self.rules is None else jax.device_put(
+            self.params, self.rules.mesh.devices.flat[0])
+        frozen, trainable = split_params(base, plan)
         spec = decode_delta_spec(plan, trainable["segments"])
         if not spec:
             raise ValueError(
@@ -400,7 +405,7 @@ class ServeEngine:
         self._delta_key = jax.random.PRNGKey(p.seed)
         self._wave = jax.jit(make_online_wave(
             self.cfg, p.sparse, p.optimizer, self._plan,
-            wave_tokens=p.train_tokens, kernels=p.use_kernels))
+            wave_tokens=p.train_tokens))
         self._zeros_delta = zeros_delta_tree
         self._deltas = DeltaStore(p.store_capacity, self._make_delta_entry)
         self._DeltaState = DeltaState

@@ -185,7 +185,7 @@ def make_train_step(tc: TrainConfig, plan: SelectionPlan,
 
 
 def make_online_wave(cfg, sparse, optimizer, plan: SelectionPlan, *,
-                     wave_tokens: int, kernels: bool = False,
+                     wave_tokens: int, kernels: Optional[bool] = None,
                      remat: str = "selected"):
     """Builds the serve engine's online personalization train wave.
 
@@ -205,9 +205,10 @@ def make_online_wave(cfg, sparse, optimizer, plan: SelectionPlan, *,
     of wave losses on one user's traffic demonstrates personalization.
     Restricted to stateless optimizers (sgd, momentum 0) — per-user state
     is the delta and nothing else, matching the compact step's bitwise
-    guarantee. The kernel-routing flag is baked in at trace time via
-    `use_kernels`, keeping the pinned 2-launch-per-leaf property: the
-    materialize/extract gathers stay on the jnp path and add no launches.
+    guarantee. `kernels` is baked in at trace time via `use_kernels` (None:
+    the Pallas kernels exactly on a TPU backend), keeping the pinned
+    2-launch-per-leaf property: the materialize/extract gathers stay on the
+    jnp path and add no launches.
     """
     from repro.configs.base import ShapeConfig
     from repro.core.delta import apply_delta_tree, extract_delta_tree
@@ -237,10 +238,7 @@ def make_online_wave(cfg, sparse, optimizer, plan: SelectionPlan, *,
             "sel_idx": sel_idx,
             "rng": rng,
         }
-        if kernels:
-            with use_kernels(True):
-                new_state, metrics = step(state, batch)
-        else:
+        with use_kernels(kernels):
             new_state, metrics = step(state, batch)
         new_vals = extract_delta_tree(
             base_segs, new_state["params_trainable"]["segments"], sel_idx,

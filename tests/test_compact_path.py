@@ -340,5 +340,5 @@ def test_moe_compact_kernel_launch_count():
     # per-kernel budget: exactly one batched-dW site per expert leaf
     # (w_gate/w_up/w_down), independent of n_experts and K
     for key, bd in breakdowns.items():
-        assert bd.get("batched_dw._kernel", 0) == 3, (key, bd)
+        assert bd.get("batched_dw", 0) == 3, (key, bd)
     assert breakdowns[k1] == breakdowns[k2], breakdowns

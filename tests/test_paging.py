@@ -122,7 +122,7 @@ def _mk_radix(num_pages=64, spill=None, **kw):
     return pool, dev, cache
 
 
-def _submit(pool, dev, cache, toks, content):
+def _submit(pool, dev, cache, toks):
     """Drive one request through the engine's cache protocol: match,
     COW the partial continuation, 'prefill' the uncovered pages, insert.
     Returns the pages the request held (already released)."""
@@ -130,9 +130,10 @@ def _submit(pool, dev, cache, toks, content):
     mr = cache.match(toks, plen - 1)
     assert mr.covered <= plen - 1
     off = 0
-    for pid, fill in mr.pages:      # matched content must be exact
-        assert content[pid][:fill * 4] == \
-            np.ascontiguousarray(toks[off:off + fill]).tobytes()[:fill * 4]
+    for pid, fill in mr.pages:      # matched pages hold exactly these rows,
+        # whether prefilled here or written back by a rehydrate
+        assert np.array_equal(dev.rows[pid * PS:pid * PS + fill, 0],
+                              toks[off:off + fill])
         off += fill
     held = [pid for pid, _ in mr.pages]
     n_full = sum(1 for _, f in mr.pages if f == PS)
@@ -141,7 +142,6 @@ def _submit(pool, dev, cache, toks, content):
             new = pool.cow_split(mr.pages[-1][0])
             lo = (len(held) - 1) * PS
             dev.fill(new, np.resize(toks[lo:], PS))
-            content[new] = np.ascontiguousarray(toks[lo:lo + PS]).tobytes()
             held[-1] = new
         else:
             pool.decref(held.pop())
@@ -150,7 +150,6 @@ def _submit(pool, dev, cache, toks, content):
         pid = pool.alloc()
         lo = len(held) * PS
         dev.fill(pid, np.resize(toks[lo:], PS))
-        content[pid] = np.ascontiguousarray(toks[lo:lo + PS]).tobytes()
         held.append(pid)
     if len(held) == n_pages:
         reg = cache.insert_pages(toks, plen // PS, held, n_full)
@@ -187,7 +186,6 @@ def _brute_force_shared_pages(toks, registered):
 def test_radix_match_vs_brute_force_lcp(seed, n_reqs, vocab):
     rng = np.random.default_rng(seed)
     pool, dev, cache = _mk_radix()
-    content = {}
     registered = []                 # prompts whose full pages all landed
     for _ in range(n_reqs):
         plen = int(rng.integers(1, 5 * PS))
@@ -201,7 +199,7 @@ def test_radix_match_vs_brute_force_lcp(seed, n_reqs, vocab):
         assert n_full >= min(_brute_force_shared_pages(toks, registered),
                              cap // PS)
         cache.abandon(mr, plen)
-        held = _submit(pool, dev, cache, toks, content)
+        held = _submit(pool, dev, cache, toks)
         if len(held) == -(-plen // PS):
             registered.append(toks)
     while cache.evict_one():        # drain: nothing may leak
@@ -222,7 +220,6 @@ def test_radix_invariants_under_random_ops(seed, n_ops):
     rng = np.random.default_rng(seed)
     spill = SpillTier(32)
     pool, dev, cache = _mk_radix(num_pages=16, spill=spill)
-    content = {}
     pinned = []                     # live matches (simulated open slots)
     for _ in range(n_ops):
         op = rng.integers(0, 4)
@@ -230,7 +227,7 @@ def test_radix_invariants_under_random_ops(seed, n_ops):
             plen = int(rng.integers(1, 4 * PS))
             toks = rng.integers(0, 3, plen).astype(np.int32)
             if pool.free_pages >= -(-plen // PS) + 1:
-                _submit(pool, dev, cache, toks, content)
+                _submit(pool, dev, cache, toks)
         elif op == 1 and cache.node_count:      # evict one leaf
             cache.evict_one()
         elif op == 2:               # match and HOLD the pin (open slot)
@@ -260,8 +257,7 @@ def test_radix_invariants_under_random_ops(seed, n_ops):
 def test_radix_pinned_never_evicted():
     pool, dev, cache = _mk_radix(num_pages=8)
     toks = np.arange(2 * PS, dtype=np.int32)
-    content = {}
-    _submit(pool, dev, cache, toks, content)
+    _submit(pool, dev, cache, toks)
     mr = cache.match(toks, 2 * PS - 1)          # pins the deepest node
     # matched pages are referenced by the match => nothing evictable
     assert cache.evictable() == 0
@@ -282,12 +278,11 @@ def test_radix_eviction_is_lru_leaf_first():
     """Two sibling branches: the least-recently-touched leaf goes first,
     and evicting a leaf makes its parent evictable next."""
     pool, dev, cache = _mk_radix(num_pages=16)
-    content = {}
     shared = np.arange(PS, dtype=np.int32)
     a = np.concatenate([shared, np.full(PS, 90, np.int32)])
     b = np.concatenate([shared, np.full(PS, 91, np.int32)])
-    _submit(pool, dev, cache, a, content)
-    _submit(pool, dev, cache, b, content)       # splits: shared + 2 leaves
+    _submit(pool, dev, cache, a)
+    _submit(pool, dev, cache, b)       # splits: shared + 2 leaves
     assert cache.node_count == 3
     # touch branch a AFTER b: b's leaf is now the LRU leaf
     cache.abandon(cache.match(a, 2 * PS - 1), 2 * PS)
@@ -313,9 +308,8 @@ def test_spill_rehydrate_roundtrip_byte_identical():
     tier; a later match re-attaches them bit-for-bit."""
     spill = SpillTier(16)
     pool, dev, cache = _mk_radix(num_pages=8, spill=spill)
-    content = {}
     toks = np.arange(3 * PS, dtype=np.int32)
-    held = _submit(pool, dev, cache, toks, content)
+    held = _submit(pool, dev, cache, toks)
     snap = {"s": np.arange(5, dtype=np.float32), "last": np.ones(2)}
     assert cache.insert_snapshot(toks, 2 * PS, {k: v.copy()
                                                 for k, v in snap.items()})
@@ -399,9 +393,8 @@ def test_partial_continuations_coexist_only_in_radix():
     p2 = np.concatenate([base, np.asarray([60, 61], np.int32)])
 
     pool, dev, cache = _mk_radix(num_pages=8)
-    content = {}
-    _submit(pool, dev, cache, p1, content)
-    _submit(pool, dev, cache, p2, content)
+    _submit(pool, dev, cache, p1)
+    _submit(pool, dev, cache, p2)
     for q in (p1, p2):
         mr = cache.match(np.append(q, 7).astype(np.int32), len(q))
         assert mr.covered == len(q), q          # full page + its partial
@@ -428,12 +421,11 @@ def test_partial_slots_lru_bounded():
     page per historical request (that would push peak page usage ABOVE
     the no-sharing run's)."""
     pool, dev, cache = _mk_radix(num_pages=16)
-    content = {}
     base = np.arange(PS, dtype=np.int32)
     tails = [np.concatenate([base, np.asarray([t, t + 1], np.int32)])
              for t in (50, 60, 70)]
     for t in tails:
-        _submit(pool, dev, cache, t, content)
+        _submit(pool, dev, cache, t)
     assert cache.node_count == 3            # spine + partial_slots leaves
     # the oldest partial was displaced: its prompt only matches the spine
     mr = cache.match(np.append(tails[0], 7).astype(np.int32), len(tails[0]))

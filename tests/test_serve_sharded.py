@@ -12,8 +12,9 @@ subprocess test forces 8 host CPU devices and proves 2-/4-way sharded
 decode token-identical to the single-device engine — and, for llama3, to
 the contiguous batch=1 oracle — for all four cache families plus a
 deepseek-style MoE, including chunked prefill crossing page boundaries, a
-radix prefix hit whose rehydration lands on the sharded pool, and a
-personalized (delta) request mix with online train waves.
+radix prefix hit whose rehydration lands on the sharded pool, a
+personalized (delta) request mix with online train waves, and the
+launcher's sharded parameter init.
 """
 import dataclasses
 import os
@@ -366,6 +367,29 @@ for n in (2, 4):
     ok = ok_par and ok_wave and ok_trace
     all_ok = all_ok and ok
     print("PRESULT", n, int(ok_par), int(ok_wave), int(ok_trace), flush=True)
+
+# --- launcher init: parameters built straight into their shardings ----------
+# `build_engine --mesh-model 4` must leave no policy-sharded leaf whole on
+# device 0, and a mesh engine without personalization keeps no other copy.
+import argparse
+from repro.launch.serve import add_serve_args, build_engine
+args = add_serve_args(argparse.ArgumentParser()).parse_args(
+    ["--arch", "llama3-8b", "--smoke", "--mesh-model", "4"])
+_, eng = build_engine(args, cfg)
+specs = D.paged_param_specs(cfg, eng.params, eng.rules)
+full = {leaf.shape for leaf, spec in zip(
+            jax.tree.leaves(eng.params),
+            jax.tree.leaves(specs, is_leaf=lambda x: x is None))
+        if any(a is not None for a in spec)}
+dev0 = jax.devices()[0]
+held = [a for a in jax.tree.leaves(vars(eng)) if isinstance(a, jax.Array)]
+whole_on_dev0 = [a.shape for a in held if a.shape in full and any(
+    s.device == dev0 and s.data.shape == a.shape
+    for s in a.addressable_shards)]
+n_sharded = len(full)
+ok_init = n_sharded > 0 and not whole_on_dev0
+all_ok = all_ok and ok_init
+print("SRESULT", n_sharded, int(ok_init), whole_on_dev0, flush=True)
 print("ALLOK", int(all_ok), flush=True)
 """
 
@@ -378,8 +402,10 @@ def test_sharded_parity_forced_multidevice():
     """8 forced host CPU devices: 2-/4-way sharded decode token-identical
     to the single-device engine for every cache family plus a deepseek-style
     MoE, with page accounting device-layout independent, run-2 prefix hits
-    rehydrating onto the sharded pool, and a personalized request mix whose
-    train waves and served tokens are mesh-width independent."""
+    rehydrating onto the sharded pool, a personalized request mix whose
+    train waves and served tokens are mesh-width independent, and
+    `build_engine --mesh-model 4` holding no policy-sharded parameter leaf
+    whole on device 0."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT, SRC],
@@ -391,4 +417,6 @@ def test_sharded_parity_forced_multidevice():
     assert len(lines) == 10, proc.stdout      # 5 archs x 2 mesh widths
     plines = [l for l in proc.stdout.splitlines() if l.startswith("PRESULT")]
     assert len(plines) == 2, proc.stdout      # personalized mix, n in (2, 4)
+    slines = [l for l in proc.stdout.splitlines() if l.startswith("SRESULT")]
+    assert len(slines) == 1, proc.stdout      # sharded launcher init
     assert "ALLOK 1" in proc.stdout, proc.stdout

@@ -125,14 +125,14 @@ sys.path.insert(0, sys.argv[1])
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.configs import get_smoke_config, ShapeConfig, SparseUpdateConfig, OptimizerConfig, TrainConfig
 from repro.sharding import default_rules, use_rules
 from repro.launch.specs import make_train_cell, rules_for
 from repro.train import make_train_state, make_train_step
 from repro.models import transformer as T
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 
 # --- sharded MoE == local MoE -------------------------------------------
 cfg = get_smoke_config("deepseek-moe-16b")
