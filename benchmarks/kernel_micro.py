@@ -8,9 +8,9 @@ compiler-reported peak temp memory).
 
 Besides the CSV rows, `run()` fills the module-level RECORDS list with
 machine-readable dicts (op, variant, shape, ratio, us, launches); kernel
-records additionally carry roofline context from `benchmarks.roofline`
-(flops, bytes, arith_intensity, bound) so each BENCH_kernels.json row shows
-which side of the TPU ridge point the op sits on next to its launch count.
+records additionally carry roofline context (`kernel_roofline`: flops,
+bytes, arith_intensity, bound) so each BENCH_kernels.json row shows which
+side of the TPU v5e ridge point the op sits on next to its launch count.
 `benchmarks.run` dumps them to BENCH_kernels.json so the perf trajectory is
 tracked across PRs."""
 from __future__ import annotations
@@ -29,10 +29,24 @@ from repro.kernels.masked_dw import (block_sparse_dw_kernel,
 from repro.kernels.scatter_blocks import block_scatter_update_kernel
 from repro.launch.hlo_analysis import kernel_launch_count
 
-from benchmarks.roofline import kernel_roofline
-
 RECORDS: list[dict] = []      # machine-readable output (BENCH_kernels.json)
 BENCH_JSON = "BENCH_kernels.json"
+
+RIDGE = 197e12 / 819e9   # TPU v5e bf16 FLOP/s over HBM bytes/s
+
+
+def kernel_roofline(flops: float, bytes_: float) -> dict:
+    """Classify one kernel by arithmetic intensity against the TPU v5e
+    ridge point: below it the kernel is memory-bound, above it
+    compute-bound."""
+    ai = float(flops) / max(float(bytes_), 1.0)
+    return {
+        "flops": float(flops),
+        "bytes": float(bytes_),
+        "arith_intensity": ai,
+        "ridge_flops_per_byte": RIDGE,
+        "bound": "compute" if ai >= RIDGE else "memory",
+    }
 
 
 def _time(fn, *args, n=5):

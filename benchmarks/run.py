@@ -7,7 +7,6 @@
     pruning_table      Paper §IV-B   (channel/pattern sparsity, FLOPs)
     memory_table       Paper's 98% feature-memory claim, per-arch
     kernel_micro       Pallas kernel oracles + fused-vs-loop + skip ratios
-    roofline           per-(arch,shape) bound classification + arith intensity
 
     PYTHONPATH=src python -m benchmarks.run [--only NAME]
 
@@ -29,7 +28,6 @@ MODULES = [
     "pruning_table",
     "memory_table",
     "kernel_micro",
-    "roofline",
     "fig2_layer_depth",
     "table2_evaluation",
 ]

@@ -82,8 +82,8 @@ class ModelConfig:
         return self.family == "ssm"
 
     def param_count(self) -> int:
-        """Analytic parameter count (matches init exactly; used for roofline
-        MODEL_FLOPS and memory accounting)."""
+        """Analytic parameter count (matches init exactly; used for memory
+        accounting)."""
         from repro.models.registry import param_count  # lazy, avoids cycle
         return param_count(self)
 

@@ -11,8 +11,7 @@ cell on the production meshes, record memory/cost/collective analysis.
 
 Outputs one JSON per cell into --out (default experiments/dryrun):
 bytes-per-device (arguments/outputs/temps), HLO flops (body-once; see
-hlo_analysis), trip-corrected collective bytes by op, and metadata used by
-benchmarks/roofline.py.
+hlo_analysis), trip-corrected collective bytes by op, and cell metadata.
 """
 import argparse
 import dataclasses

@@ -1,5 +1,5 @@
 """Arch registry: config -> model functions, plus analytic parameter counts
-(used by roofline MODEL_FLOPS and the memory-budget solver)."""
+(used by the memory-budget solver)."""
 from __future__ import annotations
 
 import jax

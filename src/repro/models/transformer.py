@@ -215,20 +215,24 @@ def _sub_sel(sel, name):
 
 def _apply_dense_block(cfg, p, x, positions, sel, window: int):
     h = L.apply_norm(p["attn_ln"], x)
-    x = x + L.attention(p["attn"], cfg, h, positions, window=window,
-                        sel=_sub_sel(sel, "attn"))
+    with jax.named_scope("token_mix"):
+        x = x + L.attention(p["attn"], cfg, h, positions, window=window,
+                            sel=_sub_sel(sel, "attn"))
     h = L.apply_norm(p["mlp_ln"], x)
-    x = x + L.apply_mlp(p["mlp"], cfg, h, sel=_sub_sel(sel, "mlp"))
+    with jax.named_scope("channel_mix"):
+        x = x + L.apply_mlp(p["mlp"], cfg, h, sel=_sub_sel(sel, "mlp"))
     return x, jnp.zeros((2,), jnp.float32)
 
 
 def _apply_moe_block(cfg, p, x, positions, sel):
     h = L.apply_norm(p["attn_ln"], x)
-    x = x + L.attention(p["attn"], cfg, h, positions,
-                        sel=_sub_sel(sel, "attn"))
+    with jax.named_scope("token_mix"):
+        x = x + L.attention(p["attn"], cfg, h, positions,
+                            sel=_sub_sel(sel, "attn"))
     h = L.apply_norm(p["mlp_ln"], x)
-    y, aux = MOE.apply_moe(p["moe"], cfg, h, sel=_sub_sel(sel, "moe"))
-    x = x + y
+    with jax.named_scope("channel_mix"):
+        y, aux = MOE.apply_moe(p["moe"], cfg, h, sel=_sub_sel(sel, "moe"))
+        x = x + y
     return x, jnp.stack([aux["load_balance"], aux["router_z"]])
 
 
@@ -240,19 +244,23 @@ def _apply_jamba_super(cfg, p, x, positions, sel):
         sub = p[f"sub{i}"]
         ssel = _sub_sel(sel, f"sub{i}")
         h = L.apply_norm(sub["mixer_ln"], x)
-        if i == attn_pos:
-            x = x + L.attention(sub["attn"], cfg, h, positions,
-                                sel=_sub_sel(ssel, "attn"))
-        else:
-            y, _ = M.apply_mamba(sub["mamba"], cfg, h, sel=_sub_sel(ssel, "mamba"))
-            x = x + y
+        with jax.named_scope("token_mix"):
+            if i == attn_pos:
+                x = x + L.attention(sub["attn"], cfg, h, positions,
+                                    sel=_sub_sel(ssel, "attn"))
+            else:
+                y, _ = M.apply_mamba(sub["mamba"], cfg, h,
+                                     sel=_sub_sel(ssel, "mamba"))
+                x = x + y
         h = L.apply_norm(sub["ffn_ln"], x)
-        if _moe_at(cfg, i):
-            y, a = MOE.apply_moe(sub["moe"], cfg, h, sel=_sub_sel(ssel, "moe"))
-            aux = aux + jnp.stack([a["load_balance"], a["router_z"]])
-        else:
-            y = L.apply_mlp(sub["mlp"], cfg, h, sel=_sub_sel(ssel, "mlp"))
-        x = x + y
+        with jax.named_scope("channel_mix"):
+            if _moe_at(cfg, i):
+                y, a = MOE.apply_moe(sub["moe"], cfg, h,
+                                     sel=_sub_sel(ssel, "moe"))
+                aux = aux + jnp.stack([a["load_balance"], a["router_z"]])
+            else:
+                y = L.apply_mlp(sub["mlp"], cfg, h, sel=_sub_sel(ssel, "mlp"))
+            x = x + y
     return x, aux
 
 
@@ -267,11 +275,14 @@ def _apply_gemma_super(cfg, p, x, positions, sel, period: int):
 
 def _apply_rwkv_block(cfg, p, x, positions, sel):
     h = L.apply_norm(p["time_ln"], x)
-    y, _ = R.apply_time_mix(p["time"], cfg, h, sel=_sub_sel(sel, "time"))
-    x = x + y
+    with jax.named_scope("token_mix"):
+        y, _ = R.apply_time_mix(p["time"], cfg, h, sel=_sub_sel(sel, "time"))
+        x = x + y
     h = L.apply_norm(p["chan_ln"], x)
-    y, _ = R.apply_channel_mix(p["chan"], cfg, h, sel=_sub_sel(sel, "chan"))
-    x = x + y
+    with jax.named_scope("channel_mix"):
+        y, _ = R.apply_channel_mix(p["chan"], cfg, h,
+                                   sel=_sub_sel(sel, "chan"))
+        x = x + y
     return x, jnp.zeros((2,), jnp.float32)
 
 
@@ -340,6 +351,7 @@ def _pick(a, b, *path):
     return None
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg, params_pair, batch):
     frozen, trainable = params_pair
     if cfg.embed_inputs:
@@ -374,12 +386,15 @@ def forward(cfg, params_pair, batch, sel=None, remat: bool = True):
             sel_idx, sel_spec = sel[0][seg.name], sel[1][seg.name]
             if len(sel) > 2 and sel[2] is not None:
                 sel_wsel = sel[2].get(seg.name)
-        x, a1 = _run_segment(cfg, seg.kind, f_stack, x, positions,
-                             None, None, remat)
-        x, a2 = _run_segment(cfg, seg.kind, t_stack, x, positions,
-                             sel_idx, sel_spec, remat, sel_wsel=sel_wsel)
+        with jax.named_scope("frozen_layers"):
+            x, a1 = _run_segment(cfg, seg.kind, f_stack, x, positions,
+                                 None, None, remat)
+        with jax.named_scope("trainable_layers"):
+            x, a2 = _run_segment(cfg, seg.kind, t_stack, x, positions,
+                                 sel_idx, sel_spec, remat, sel_wsel=sel_wsel)
         aux = aux + a1 + a2
-    x = L.apply_norm(_pick(frozen, trainable, "final_norm"), x)
+    with jax.named_scope("head_loss"):   # the final norm feeds only the head
+        x = L.apply_norm(_pick(frozen, trainable, "final_norm"), x)
     return x, aux
 
 
@@ -416,8 +431,9 @@ def chunked_cross_entropy(hidden, w_head, labels, chunk: int = CE_CHUNK):
 def loss_fn(cfg, params_pair, batch, sel=None, remat: bool = True,
             aux_weight: float = 0.01, z_weight: float = 1e-3):
     hidden, aux = forward(cfg, params_pair, batch, sel=sel, remat=remat)
-    w_head = lm_head_weight(cfg, params_pair)
-    total, count = chunked_cross_entropy(hidden, w_head, batch["labels"])
-    ce = total / count
+    with jax.named_scope("head_loss"):
+        w_head = lm_head_weight(cfg, params_pair)
+        total, count = chunked_cross_entropy(hidden, w_head, batch["labels"])
+        ce = total / count
     loss = ce + aux_weight * aux[0] + z_weight * aux[1]
     return loss, {"ce": ce, "load_balance": aux[0], "router_z": aux[1]}

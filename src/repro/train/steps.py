@@ -122,7 +122,8 @@ def make_train_step(tc: TrainConfig, plan: SelectionPlan,
         key = jax.random.fold_in(state["rng"], step)
         sel_idx = state["sel_idx"]
         if use_selection and tc.sparse.enabled and sel_idx is not None:
-            sel_idx = maybe_reselect(plan, tc.sparse, sel_idx, step, key)
+            with jax.named_scope("reselect"):
+                sel_idx = maybe_reselect(plan, tc.sparse, sel_idx, step, key)
             sel = (sel_idx, plan.spec)
         else:
             sel = None
@@ -131,8 +132,9 @@ def make_train_step(tc: TrainConfig, plan: SelectionPlan,
         if compact_grads and sel is not None:
             from repro.core.sparse_update import (gather_selected_tree,
                                                   map_selectable)
-            wsel = gather_selected_tree(trainable.get("segments", {}),
-                                        sel_idx, plan.spec)
+            with jax.named_scope("update"):
+                wsel = gather_selected_tree(trainable.get("segments", {}),
+                                            sel_idx, plan.spec)
             spec_top = {"segments": plan.spec}
 
             def loss_of(diff):
@@ -147,9 +149,10 @@ def make_train_step(tc: TrainConfig, plan: SelectionPlan,
 
             (loss, metrics), (g_dense, g_sel) = jax.value_and_grad(
                 loss_of, has_aux=True)((trainable, wsel))
-            new_params, new_opt = apply_updates_mixed(
-                tc.optimizer, trainable, g_dense, g_sel, state["opt"], step,
-                sel_idx, plan.spec)
+            with jax.named_scope("update"):
+                new_params, new_opt = apply_updates_mixed(
+                    tc.optimizer, trainable, g_dense, g_sel, state["opt"],
+                    step, sel_idx, plan.spec)
         else:
             def loss_of(t_tree):
                 return T.loss_fn(cfg, (state["params_frozen"], t_tree),
@@ -166,8 +169,9 @@ def make_train_step(tc: TrainConfig, plan: SelectionPlan,
                 grads = dict(grads)
                 grads["segments"] = compress_grads(grads["segments"], sel_idx,
                                                    plan.spec, logical)
-            new_params, new_opt = apply_updates(tc.optimizer, trainable,
-                                                grads, state["opt"], step)
+            with jax.named_scope("update"):
+                new_params, new_opt = apply_updates(tc.optimizer, trainable,
+                                                    grads, state["opt"], step)
         new_state = {
             "step": step + 1,
             "params_trainable": new_params,
