@@ -102,8 +102,8 @@ def block_act_prune_ref(x, threshold: float = 0.15, block: int = 2):
 
 
 def wkv6_ref(r, k, v, w, u):
-    """Sequential RWKV-6 recurrence oracle (matches models/rwkv6._wkv_chunk
-    semantics): r,k,v,w: [BH, T, D]; u: [D] -> y [BH, T, D] fp32."""
+    """Sequential RWKV-6 recurrence oracle (the recurrence of models/rwkv6,
+    from a zero state): r,k,v,w: [BH, T, D]; u: [D] -> y [BH, T, D] fp32."""
     import jax
 
     def step(s, rkvw):

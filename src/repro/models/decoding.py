@@ -992,19 +992,7 @@ def _mamba_last_state(p, cfg, x):
 
 
 def _rwkv_prefill_time(p, cfg, x):
-    y, _ = R.apply_time_mix(p, cfg, x)
-    # final state via a dedicated wkv pass
-    b, s, d = x.shape
-    hd = cfg.rwkv.head_dim
-    h = R.num_heads(cfg)
-    xp = R._shift(x)
-    mu = p["mu"].astype(x.dtype)
-    xr, xk, xv, xg, xw = [x + (xp - x) * mu[i] for i in range(5)]
-    r = jnp.matmul(xr, p["wr"]).reshape(b, s, h, hd).astype(jnp.float32)
-    k = jnp.matmul(xk, p["wk"]).reshape(b, s, h, hd).astype(jnp.float32)
-    v = jnp.matmul(xv, p["wv"]).reshape(b, s, h, hd).astype(jnp.float32)
-    wlog = p["w0"] + jnp.tanh(xw.astype(jnp.float32) @ p["wA"]) @ p["wB"]
-    w = jnp.exp(-jnp.exp(wlog)).reshape(b, s, h, hd)
-    s0 = jnp.zeros((b, h, hd, hd), jnp.float32)
-    _, s_last = R.wkv(r, k, v, w, p["u"], s0)
-    return y, {"s": s_last, "last": x[:, -1]}
+    """Time mix over the whole prompt from an empty state, returning the
+    final wkv state and token-shift vector as the cache."""
+    cache = R.init_rwkv_cache(cfg, x.shape[0], x.dtype)["time"]
+    return R.apply_time_mix(p, cfg, x, cache=cache)

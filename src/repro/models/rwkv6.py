@@ -4,12 +4,25 @@ WKV recurrence (per head, head_dim D):
     y_t = r_t · (diag(u) k_t v_tᵀ + S_{t-1})
     S_t = diag(w_t) S_{t-1} + k_t v_tᵀ
 with per-channel decay w_t = exp(-exp(wlog_t)) produced by a low-rank
-data-dependent path (the Finch contribution).
+data-dependent path (the Finch contribution). The recurrence takes the
+log-decay lw_t = -exp(wlog_t) <= 0, so a decay that underflows to 0 stays a
+finite number.
 
-Implementation: lax.scan over time in chunks with jax.checkpoint (memory
-O(chunk); the state is [B, H, D, D]). Sequential-scan latency on real TPU is
-the motivation for the chunked Pallas kernel listed in DESIGN §6; for
-correctness, dry-run lowering, and CPU validation this form is exact.
+Implementation: `wkv` scans over chunks of CHUNK tokens with the state
+S [B, H, D, D] (f32) as the carry and jax.checkpoint on the chunk body, so
+memory is O(chunk) and a backward pass saves one state per chunk. Inside a
+chunk the recurrence has a closed form. With b_t the cumulative log-decay
+inside the chunk (b_{-1} = 0):
+    y_t = (r_t ⊙ e^{b_{t-1}}) · S
+        + Σ_{u<t} [Σ_d r_{t,d} k_{u,d} e^{b_{t-1,d} − b_{u,d}}] v_u
+        + (Σ_d r_{t,d} u_d k_{t,d}) v_t
+    S'  = diag(e^{b_{Q-1}}) S + Σ_u (k_u ⊙ e^{b_{Q-1} − b_u}) v_uᵀ
+so the state is read and written once per chunk and the work inside it is
+matmuls (`_wkv_chunk`). Every exponent is kept at or below zero: the chunk
+is split into sub-chunks of SUB tokens; a key in an earlier sub-chunk is
+decayed to the last token before the query's sub-chunk and the query from
+there, and pairs inside one sub-chunk are formed elementwise. A one-token
+call (decode) takes the stepwise update (`_wkv_step`).
 
 Simplification vs the full Finch block (recorded in DESIGN §8): the five
 token-shift interpolations use per-channel learned mu (RWKV-5 style lerp)
@@ -22,13 +35,16 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.sparse_update import smm
 from repro.models.common import dense_init, last_valid, row_matmul
 from repro.models.layers import apply_norm, init_norm
 from repro import sharding as SH
 
-CHUNK = 32
+CHUNK = 32             # tokens per step of the state scan
+SUB = 16               # tokens per sub-chunk inside a chunk
+LOG_DECAY_MIN = -104.0  # below this e^lw is 0 in f32: a decay of zero
 DECAY_LORA = 64
 
 
@@ -66,44 +82,109 @@ def _shift(x, last=None):
     return jnp.concatenate([last, x[:, :-1]], axis=1)
 
 
-def _wkv_chunk(u, carry, chunk):
-    """carry: S [B,H,D,D]; chunk: r,k,v [B,Q,H,D], w [B,Q,H,D] (decay)."""
-    s = carry
-    r, k, v, w = chunk
-
-    def step(s, rkvw):
-        rt, kt, vt, wt = rkvw                    # [B,H,D]
-        kv = kt[..., :, None] * vt[..., None, :]  # [B,H,D,D]
-        y = jnp.einsum("bhd,bhde->bhe", rt, u[None, :, :, None] * kt[..., :, None]
-                       * vt[..., None, :] + s)
-        s = wt[..., :, None] * s + kv
-        return s, y
-
-    s, ys = jax.lax.scan(step, s, (r.swapaxes(0, 1), k.swapaxes(0, 1),
-                                   v.swapaxes(0, 1), w.swapaxes(0, 1)))
-    return s, ys.swapaxes(0, 1)                  # [B,Q,H,D]
+def _wkv_step(u, s, r, k, v, lw):
+    """One token. s: [B,H,D,D]; r,k,v,lw: [B,H,D] -> (S', y [B,H,D] f32)."""
+    r, k, v = (t.astype(jnp.float32) for t in (r, k, v))
+    kv = k[..., :, None] * v[..., None, :]                    # [B,H,D,D]
+    y = jnp.einsum("bhd,bhde->bhe", r, u[None, :, :, None] * kv + s)
+    return jnp.exp(lw)[..., :, None] * s + kv, y
 
 
-def wkv(r, k, v, w, u, s0):
-    """r,k,v,w: [B,S,H,D] fp32; s0: [B,H,D,D] -> (y [B,S,H,D], s_last)."""
+def _excl_cumsum(x, axis, reverse=False):
+    """Sum of the elements before each one along `axis` (after it, with
+    `reverse`), as a sum and not as a difference of running sums."""
+    inc = jax.lax.cumsum(x, axis=axis, reverse=reverse)
+    zero = jnp.zeros_like(jax.lax.slice_in_dim(x, 0, 1, axis=axis))
+    if reverse:
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(inc, 1, None, axis=axis), zero], axis)
+    return jnp.concatenate(
+        [zero, jax.lax.slice_in_dim(inc, 0, -1, axis=axis)], axis)
+
+
+def _wkv_chunk(u, s, chunk):
+    """One chunk in closed form. s: [B,H,D,D]; chunk: r,k,v,lw [B,Q,H,D]
+    -> (S', y [B,Q,H,D] in the dtype of r). Runs in f32; every contraction
+    at f32 accuracy."""
+    r, k, v, lw = chunk
+    dtype = r.dtype
+    b, q, h, d = r.shape
+    c = min(SUB, q)
+    n = q // c
+    hi = jax.lax.Precision.HIGHEST
+    r, k, v = (t.astype(jnp.float32).swapaxes(1, 2).reshape(b, h, n, c, d)
+               for t in (r, k, v))
+    lw = jnp.maximum(lw, LOG_DECAY_MIN).swapaxes(1, 2).reshape(b, h, n, c, d)
+
+    # log-decay summed inside each sub-chunk: up to and including token t
+    # (a), before it (a_ex), after it (suf); and over whole sub-chunks
+    # before sub-chunk i, after it, and strictly between j and i
+    a = jnp.cumsum(lw, axis=3)
+    a_ex, suf = _excl_cumsum(lw, 3), _excl_cumsum(lw, 3, reverse=True)
+    tot = a[:, :, :, -1]                                      # [B,H,n,D]
+    before, after = _excl_cumsum(tot, 2), _excl_cumsum(tot, 2, reverse=True)
+    lower = np.tril(np.ones((n, n), bool), -1)                # [i, m]: m < i
+    between = jnp.sum(jnp.where((lower[:, None] & lower.T[None])[..., None],
+                                tot[:, :, None, None], 0.0), axis=4)
+
+    # the state entering the chunk, and the state leaving it
+    rq = r * jnp.exp(a_ex + before[:, :, :, None])           # e^{b_{t-1}}
+    y = jnp.einsum("bhntd,bhde->bhnte", rq, s, precision=hi)
+    kq = k * jnp.exp(suf + after[:, :, :, None])             # e^{b_{Q-1}-b_u}
+    s = (jnp.exp(tot.sum(axis=2))[..., None] * s
+         + jnp.einsum("bhntd,bhnte->bhde", kq, v, precision=hi))
+
+    # query in sub-chunk i, key in sub-chunk j < i: both decayed to the last
+    # token before sub-chunk i
+    rs = r * jnp.exp(a_ex)
+    ks = k[:, :, None] * jnp.exp(suf[:, :, None] + between[:, :, :, :, None])
+    att = jnp.einsum("bhitd,bhijsd->bhitjs", rs, ks, precision=hi)
+    # query and key in one sub-chunk: pairwise, the bonus u on the diagonal
+    tri = np.tril(np.ones((c, c), bool), -1)[..., None]       # [t, s, 1]
+    expo = jnp.where(tri, a_ex[:, :, :, :, None] - a[:, :, :, None], 0.0)
+    diag = jnp.sum(r[:, :, :, :, None] * k[:, :, :, None]
+                   * jnp.where(tri, jnp.exp(expo), 0.0), axis=-1)
+    bonus = jnp.sum(r * u[None, :, None, None] * k, axis=-1)  # [B,H,n,c]
+    diag = diag + bonus[..., None] * np.eye(c, dtype=np.float32)
+    att = jnp.where(lower[:, None, :, None], att,
+                    jnp.where(np.eye(n, dtype=bool)[:, None, :, None],
+                              diag[:, :, :, :, None], 0.0))
+    y = y.reshape(b, h, q, d) + jnp.einsum(
+        "bhts,bhse->bhte", att.reshape(b, h, q, q), v.reshape(b, h, q, d),
+        precision=hi)
+    return s, y.astype(dtype).swapaxes(1, 2)
+
+
+def wkv(r, k, v, lw, u, s0):
+    """r,k,v: [B,S,H,D]; lw: f32 log-decay <= 0 [B,S,H,D]; u: [H,D];
+    s0: [B,H,D,D] f32 -> (y [B,S,H,D] in the dtype of r, s_last). The
+    recurrence runs in f32 whatever the dtype of r, k and v. A sequence
+    that is not a whole number of chunks is padded with tokens that leave
+    the state as it is."""
     b, s, h, d = r.shape
-    q = min(CHUNK, s)
-    assert s % q == 0
-    nc = s // q
-    resh = lambda t: t.reshape(b, nc, q, h, d).swapaxes(0, 1)
+    c = min(SUB, s)
+    q = min(CHUNK, -(-s // c) * c)
+    pad = -s % q
+    nc = (s + pad) // q
+
+    def resh(t):                                  # -> [nc, B, Q, H, D]
+        t = jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return t.reshape(b, nc, q, h, d).swapaxes(0, 1)
+
     body = jax.checkpoint(partial(_wkv_chunk, u))
-    s_last, ys = jax.lax.scan(body, s0, (resh(r), resh(k), resh(v), resh(w)))
-    return ys.swapaxes(0, 1).reshape(b, s, h, d), s_last
+    s_last, ys = jax.lax.scan(body, s0, tuple(map(resh, (r, k, v, lw))))
+    y = ys.swapaxes(0, 1).reshape(b, s + pad, h, d)
+    return y[:, :s], s_last
 
 
 def apply_time_mix(p, cfg, x, sel=None, cache=None, length=None):
     """x: [B,S,d]. cache (decode): {"s": [B,H,D,D], "last": [B,d]}.
 
     length [B] (cached path, None = all s): valid tokens per row. Padded
-    rows must not advance the wkv state — their decay is forced to 1 and
-    their key to 0 (S_t = 1·S + 0), and the token-shift "last" is taken at
-    the per-row valid end, so the cache comes back exactly as after the
-    valid prefix."""
+    rows must not advance the wkv state — their log-decay is forced to 0
+    and their key to 0 (S_t = 1·S + 0), and the token-shift "last" is
+    taken at the per-row valid end, so the cache comes back exactly as
+    after the valid prefix."""
     b, s, d = x.shape
     hd = cfg.rwkv.head_dim
 
@@ -129,13 +210,12 @@ def apply_time_mix(p, cfg, x, sel=None, cache=None, length=None):
 
     # decay lora: wA replicated (tiny), w0/wB sharded with the head block
     wlog = p["w0"] + jnp.tanh(xw.astype(jnp.float32) @ p["wA"]) @ p["wB"]
-    w = jnp.exp(-jnp.exp(wlog)).reshape(b, s, -1, hd)         # decay in (0,1)
+    lw = -jnp.exp(wlog).reshape(b, s, -1, hd)   # log-decay: w = e^lw in [0,1)
 
-    r32, k32, v32 = (t.astype(jnp.float32) for t in (r, k, v))
     if length is not None and s > 1:
         valid = (jnp.arange(s)[None, :] < length[:, None])[:, :, None, None]
-        k32 = jnp.where(valid, k32, 0.0)      # kv outer product vanishes
-        w = jnp.where(valid, w, 1.0)          # identity decay: S frozen
+        k = jnp.where(valid, k, 0.0)          # kv outer product vanishes
+        lw = jnp.where(valid, lw, 0.0)        # identity decay: S frozen
     h_eff = r.shape[2]
     if cache is None:
         s0 = jnp.zeros((b, h_eff, hd, hd), jnp.float32)
@@ -147,9 +227,11 @@ def apply_time_mix(p, cfg, x, sel=None, cache=None, length=None):
     else:
         s0 = cache["s"]
     if s == 1:  # decode fast path
-        s_new, y = _wkv_chunk(p["u"], s0, (r32, k32, v32, w))
+        s_new, y = _wkv_step(p["u"], s0, r[:, 0], k[:, 0], v[:, 0],
+                             lw[:, 0])
+        y = y[:, None]
     else:
-        y, s_new = wkv(r32, k32, v32, w, p["u"], s0)
+        y, s_new = wkv(r, k, v, lw, p["u"], s0)
 
     if local:
         # ln_x normalizes over the FULL d: gather the head blocks (exact —

@@ -11,6 +11,7 @@ import pytest
 from repro.configs import (ARCH_IDS, OptimizerConfig, ShapeConfig,
                            SparseUpdateConfig, TrainConfig, get_smoke_config)
 from repro.models import decoding as D
+from repro.models import rwkv6 as R6
 from repro.models import transformer as T
 
 
@@ -225,6 +226,114 @@ def test_rwkv_chunked_matches_stepwise():
     out_step = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(out_full), np.asarray(out_step),
                                rtol=2e-3, atol=2e-4)
+
+
+def _wkv_stepwise(r, k, v, lw, u, s0):
+    """Plain token-by-token wkv: S_t = diag(e^lw_t) S + k_t v_tᵀ."""
+    def step(s, rkvl):
+        rt, kt, vt, lt = rkvl
+        kv = kt[..., :, None] * vt[..., None, :]
+        y = jnp.einsum("bhd,bhde->bhe", rt, u[None, :, :, None] * kv + s,
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.exp(lt)[..., :, None] * s + kv, y
+
+    s_last, ys = jax.lax.scan(step, s0, tuple(t.swapaxes(0, 1)
+                                             for t in (r, k, v, lw)))
+    return ys.swapaxes(0, 1), s_last
+
+
+def _wkv_inputs(seq, decay, b=2, h=3, d=16):
+    """r, k, v, log-decay lw [B,S,H,D], u [H,D] and a nonzero s0."""
+    ks = jax.random.split(jax.random.PRNGKey(seq), 7)
+    r, k, v = (jax.random.normal(ks[i], (b, seq, h, d)) for i in range(3))
+    shape = (b, seq, h, d)
+    if decay == "weak":       # the model's init: wlog = -6 ± 0.5
+        lw = -jnp.exp(-6.0 + jax.random.uniform(ks[3], shape, minval=-0.5,
+                                                maxval=0.5))
+    elif decay == "medium":   # log w ≈ -1
+        lw = -jnp.exp(0.3 * jax.random.normal(ks[3], shape))
+    else:                     # w down to 1e-5; w = 0 in channels 0 and 1
+        lw = -jnp.exp(jax.random.uniform(ks[3], shape, minval=-6.0,
+                                         maxval=np.log(11.5)))
+        lw = lw.at[..., 0].set(-200.0).at[..., 1].set(-jnp.exp(100.0))
+    u = 0.5 * jax.random.normal(ks[4], (h, d))
+    s0 = jax.random.normal(ks[5], (b, h, d, d))
+    return r, k, v, lw, u, s0
+
+
+DECAYS = ("weak", "medium", "strong")
+WKV_LENGTHS = (8, R6.CHUNK, 3 * R6.CHUNK, R6.CHUNK + 5)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("seq", WKV_LENGTHS)
+def test_wkv_chunk_form_matches_stepwise(seq, decay):
+    r, k, v, lw, u, s0 = _wkv_inputs(seq, decay)
+    y, s_last = jax.jit(R6.wkv)(r, k, v, lw, u, s0)
+    y_ref, s_ref = jax.jit(_wkv_stepwise)(r, k, v, lw, u, s0)
+    assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(s_last).all())
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s_last), np.asarray(s_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+def test_wkv_bf16_inputs_run_in_f32(decay):
+    """bf16 r, k, v (the projections' dtype) give the f32 recurrence: the
+    state as from their f32 copies, and y rounded once to bf16."""
+    r, k, v, lw, u, s0 = _wkv_inputs(2 * R6.CHUNK, decay)
+    rb, kb, vb = (t.astype(jnp.bfloat16) for t in (r, k, v))
+    y, s_last = jax.jit(R6.wkv)(rb, kb, vb, lw, u, s0)
+    y_ref, s_ref = jax.jit(_wkv_stepwise)(
+        *(t.astype(jnp.float32) for t in (rb, kb, vb)), lw, u, s0)
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(s_last), np.asarray(s_ref),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(y.astype(jnp.float32)),
+                               np.asarray(y_ref.astype(jnp.bfloat16)
+                                          .astype(jnp.float32)),
+                               rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+def test_wkv_padded_tail_leaves_state(decay):
+    """Tokens with log-decay 0 and key 0 (serve prefill padding) after a
+    valid prefix leave the state at the prefix's."""
+    n = R6.CHUNK + 5
+    r, k, v, lw, u, s0 = _wkv_inputs(2 * R6.CHUNK, decay)
+    valid = (jnp.arange(2 * R6.CHUNK) < n)[None, :, None, None]
+    y, s_last = jax.jit(R6.wkv)(r, jnp.where(valid, k, 0.0), v,
+                                jnp.where(valid, lw, 0.0), u, s0)
+    y_ref, s_ref = jax.jit(_wkv_stepwise)(r[:, :n], k[:, :n], v[:, :n],
+                                          lw[:, :n], u, s0)
+    np.testing.assert_allclose(np.asarray(y[:, :n]), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s_last), np.asarray(s_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+def test_wkv_chunk_form_gradients_match_stepwise(decay):
+    """d/d(r, k, v, lw, u, s0) of a random projection of (y, s_last): the
+    backward the trainable suffix runs."""
+    args = _wkv_inputs(3 * R6.CHUNK, decay, b=1, h=2, d=8)
+    ky, ks = jax.random.split(jax.random.PRNGKey(7))
+    py = jax.random.normal(ky, args[0].shape)
+    ps = jax.random.normal(ks, args[5].shape)
+
+    def loss(fn):
+        def f(*a):
+            y, s_last = fn(*a)
+            return jnp.sum(y * py) + jnp.sum(s_last * ps)
+        return jax.jit(jax.grad(f, argnums=tuple(range(6))))
+
+    got = loss(R6.wkv)(*args)
+    want = loss(_wkv_stepwise)(*args)
+    for name, g, w in zip(("r", "k", "v", "lw", "u", "s0"), got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
 
 
 def test_mobilenet_smoke():
