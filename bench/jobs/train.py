@@ -32,11 +32,20 @@ NUMBERS = check.NUMBERS
 
 
 def program_config(entry: dict):
-    from repro.configs.base import ModelConfig, RWKVConfig
+    """The program's ModelConfig from a configuration file's `model` keys: a
+    nested `moe`, `ssm` or `rwkv` object becomes its sub-config, built from
+    all of its keys; `rwkv_head_dim` is the RWKV head size."""
+    from repro.configs.base import (ModelConfig, MoEConfig, RWKVConfig,
+                                    SSMConfig)
     m = dict(entry["model"])
     hd = m.pop("rwkv_head_dim", None)
-    return ModelConfig(name=entry["name"],
-                       rwkv=RWKVConfig(head_dim=hd) if hd else None, **m)
+    if hd:
+        m["rwkv"] = {"head_dim": hd}
+    for key, sub in (("moe", MoEConfig), ("ssm", SSMConfig),
+                     ("rwkv", RWKVConfig)):
+        if isinstance(m.get(key), dict):
+            m[key] = sub(**m[key])
+    return ModelConfig(name=entry["name"], **m)
 
 
 def train_config(cfg, mix: dict):
@@ -73,27 +82,39 @@ def state_rng(state_key):
 
 
 def kernel_calls(ref, m: dict, mix: dict) -> list:
-    """The sparse-update kernel calls of one step, from the shapes."""
-    from bench.reference.common import sel_spec
+    """The sparse-update kernel calls of one step, from the shapes: per
+    selectable leaf and trainable layer a `masked_dw` over all tokens, or
+    for an expert leaf a `batched_dw` over the rows per expert that the
+    program's dispatch gives it (`ref.expert_rows`); per leaf one
+    `fused_block_opt` over every trainable layer's (and expert's) fan-in."""
+    from bench.reference.common import leaf_experts, sel_spec
     k = mix["update_layers"]
     tokens = mix["batch"] * mix["seq"]
+    isz = jnp.dtype(m["dtype"]).itemsize
     state = {"sgd": 0, "momentum": 1, "adamw": 2}[mix["optimizer"]["kind"]]
     if mix["optimizer"]["kind"] == "sgd" and mix["optimizer"]["momentum"]:
         state = 1
     calls = []
-    for _p, fan_in, out in ref.selectable_leaves(m):
-        block, _nb, n_sel = sel_spec(out, mix["update_ratio"],
+    for leaf in ref.selectable_leaves(m):
+        fan_in, e = leaf[1], leaf_experts(leaf)
+        block, _nb, n_sel = sel_spec(leaf[2], mix["update_ratio"],
                                      mix["channel_block"])
         cols = n_sel * block
-        calls += [("masked_dw", {"m": tokens, "k": fan_in, "cols": cols,
-                                 "itemsize": 2})] * k
-        calls.append(("fused_block_opt", {"rows": k * fan_in, "cols": cols,
-                                          "itemsize": 2, "state": state}))
+        if e:
+            calls += [("batched_dw", {"e": e, "c": ref.expert_rows(m, mix),
+                                      "k": fan_in, "cols": cols,
+                                      "itemsize": isz})] * k
+        else:
+            calls += [("masked_dw", {"m": tokens, "k": fan_in, "cols": cols,
+                                     "itemsize": isz})] * k
+        calls.append(("fused_block_opt", {"rows": k * max(e, 1) * fan_in,
+                                          "cols": cols, "itemsize": isz,
+                                          "state": state}))
     return calls
 
 
-def _seg(tree, ref):
-    return tree["segments"][ref.SEGMENT]
+def _seg(tree, name: str):
+    return tree["segments"][name]
 
 
 def _flat(tree) -> dict:
@@ -111,6 +132,9 @@ class Job:
         self.m = cfg_entry["model"]
         self.ref = ctx["load_reference"](cfg_entry["reference"])
         self.cfg = program_config(cfg_entry)
+        # the trainable suffix: the last layers of the program's last segment
+        self.seg, self.first = TR.suffix_segment(
+            self.ref, self.m, mix["update_layers"])
         self.tc = train_config(self.cfg, mix)
         self.tokens = mix["batch"] * mix["seq"]
         # a planted fault (the tests'): fn(job, batch) -> loss in place of
@@ -173,9 +197,9 @@ class Job:
             losses.append(float(self.run_step()))
             if i == 0:
                 t = time.perf_counter()
-                seg = _seg(self.state["params_trainable"], self.ref)
+                seg = _seg(self.state["params_trainable"], self.seg)
                 if opt["kind"] == "adamw":
-                    mu = _flat(_seg(self.state["opt"]["mu"], self.ref))
+                    mu = _flat(_seg(self.state["opt"]["mu"], self.seg))
                     prog["grad_norms"] = {
                         k: float(TR._norm(v)) / (1 - opt["beta1"])
                         for k, v in mu.items()}
@@ -184,7 +208,7 @@ class Job:
                 read_s += time.perf_counter() - t
         t = time.perf_counter()
         prog["wn"] = jax.device_get(
-            _flat(_seg(self.state["params_trainable"], self.ref)))
+            _flat(_seg(self.state["params_trainable"], self.seg)))
         prog["losses"] = losses
         read_s += time.perf_counter() - t
         return prog, read_s
@@ -192,17 +216,20 @@ class Job:
     # -- window --------------------------------------------------------------
     def window(self, seconds: float) -> dict:
         """Calls the step until `seconds` have passed since the last set-up
-        step was ready; counts the steps whose loss is ready inside."""
+        step was ready; counts the steps whose loss is ready inside, and
+        keeps the host-clock intervals between them (for the log: whether
+        a slow window lost its time in one stall or in every step)."""
         t_start = time.perf_counter()
         deadline = t_start + seconds
         pending = self.run_step()
-        done, t_last, bad = 0, t_start, 0
+        done, t_last, bad, gaps = 0, t_start, 0, []
         while True:
             nxt = self.run_step()
             loss = float(pending)
             t = time.perf_counter()
             if t > deadline:
                 break
+            gaps.append(t - t_last)
             done, t_last = done + 1, t
             bad += not np.isfinite(loss)
             pending = nxt
@@ -210,7 +237,7 @@ class Job:
         if done == 0:
             raise SystemExit(f"bench: no step finished inside {seconds} s")
         return {"steps": done, "seconds": t_last - t_start,
-                "non_finite": bad,
+                "non_finite": bad, "gaps": sorted(gaps),
                 "tokens_per_s": done * self.tokens / (t_last - t_start)}
 
     def traced(self, trace_dir: str) -> dict:
@@ -246,8 +273,8 @@ class Job:
         r = TR.Reference(self.ref, self.m, self.mix, mode)
         out = r.run(params, self.batches, state_rng(self.skey),
                     self.mix["checked_steps"], rows=rows)
-        first = self.m["num_layers"] - self.mix["update_layers"]
-        out["w0"] = {TR.key_of(p): TR._get(_seg(params, self.ref), p)[first:]
+        out["w0"] = {TR.key_of(p): TR._get(_seg(params, self.seg),
+                                           p)[self.first:]
                      for p in TR.seg_paths(self.ref, self.m)}
         return out
 
@@ -282,9 +309,11 @@ def run(ctx: dict, cfg_entry: dict, mix: dict, seed: int, seconds: float,
     compiles_before = ctx["compiles"]()
     win = job.window(seconds)
     in_window = ctx["compiles"]() - compiles_before
+    gaps = win["gaps"]
     log(f"window: {win['steps']} steps in {win['seconds']:.6f} s, "
         f"{win['tokens_per_s']:.6f} tokens/s; compilations inside the "
-        f"window: {in_window}")
+        f"window: {in_window}; s between steps: median "
+        f"{gaps[len(gaps) // 2]:.6f}, longest {gaps[-3:][::-1]}")
     peak = ctx["peak_bytes"]()
     log(f"peak_bytes_in_use after the window: {peak} B (compiled step: "
         f"arguments + temporaries "
@@ -301,6 +330,12 @@ def run(ctx: dict, cfg_entry: dict, mix: dict, seed: int, seconds: float,
         log(f"trace: {t['events']} events, window {t['window_s']:.6f} s, "
             f"busy {t['busy_s']:.6f} s, steps {t['steps']}, kernels "
             f"{t['kernel_s']}")
+        t0 = time.perf_counter()
+        layer["program_text"] = job.step.as_text()
+        log(f"program text of the run's compiled step for the scope "
+            f"readers: {len(layer['program_text'])} characters in "
+            f"{time.perf_counter() - t0:.3f} s, in place of a second "
+            f"compile of the step")
     job.free()
 
     t = time.perf_counter()

@@ -34,11 +34,20 @@ class Sel(NamedTuple):
     """A weight [in, out] whose selected output blocks carry a trainable
     offset: w + delta on blocks idx. `mm` computes x @ w + the selected
     columns' x @ delta, so the gradient reaches delta without a full-shape
-    weight gradient ever existing."""
+    weight gradient ever existing. A stacked expert weight [E, in, out]
+    carries delta [E, in, n_sel, block] under one idx for all experts;
+    `expert(w, e)` takes one expert's [in, out] view of either."""
     w: jax.Array
     idx: jax.Array
     delta: jax.Array
     block: int
+
+
+def expert(w, e: int):
+    """Expert e of a stacked expert weight [E, in, out], or of its Sel."""
+    if isinstance(w, Sel):
+        return Sel(w.w[e], w.idx, w.delta[e], w.block)
+    return w[e]
 
 
 def _einsum(spec, a, b, mode):
@@ -71,6 +80,17 @@ def layernorm(p, x, eps: float = 1e-6):
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     y = (x - mu) / jnp.sqrt(var + eps)
     return y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
+
+
+def rmsnorm(p, x, eps: float = 1e-6):
+    x = x.astype(jnp.float32)
+    y = x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps)
+    return y * p["scale"].astype(jnp.float32)
+
+
+def norm(p, x):
+    """LayerNorm where the norm's weights hold a bias, else RMSNorm."""
+    return layernorm(p, x) if "bias" in p else rmsnorm(p, x)
 
 
 def mean_cross_entropy(h, w_head, labels, mode: str, chunk: int = 1024):
@@ -111,34 +131,42 @@ def sel_spec(out_dim: int, ratio: float, block_req: int):
     return block, n_blocks, max(1, int(round(ratio * n_blocks)))
 
 
+def leaf_experts(leaf) -> int:
+    """Experts of a selectable leaf (path, in_dim, out_dim[, experts]): 0
+    for a plain [in, out] weight."""
+    return leaf[3] if len(leaf) > 3 else 0
+
+
 def draw_selection(state_key, step: int, segment: str, leaves, k_layers: int,
                    ratio: float, block_req: int) -> dict:
     """The selection of one step of the dynamic phase: for each selectable
-    leaf (`leaves` is [(path, in_dim, out_dim)] in the order of the sorted
-    leaf paths), n_sel of n_blocks blocks per trainable layer, the first
-    n_sel of a uniform random permutation. The key is the train state's key
-    folded with the step and then with the segment's crc32, split once per
-    leaf. Returns {path: int32 [k_layers, n_sel]}."""
+    leaf (`leaves` is [(path, in_dim, out_dim[, experts])] in the order of
+    the sorted leaf paths), n_sel of n_blocks blocks per trainable layer,
+    the first n_sel of a uniform random permutation; an expert leaf's draw
+    is shared by all its experts. The key is the train state's key folded
+    with the step and then with the segment's crc32, split once per leaf.
+    Returns {path: int32 [k_layers, n_sel]}."""
     key = jax.random.fold_in(state_key, step)
     key = jax.random.fold_in(key, zlib.crc32(segment.encode()) % 2**31)
     keys = jax.random.split(key, max(1, len(leaves)))
     out = {}
-    for k, (path, _in, out_dim) in zip(keys, leaves):
-        _block, n_blocks, n_sel = sel_spec(out_dim, ratio, block_req)
+    for k, leaf in zip(keys, leaves):
+        _block, n_blocks, n_sel = sel_spec(leaf[2], ratio, block_req)
         u = jax.random.uniform(k, (k_layers, 1, n_blocks))
-        out[path] = jnp.argsort(u, axis=-1)[:, 0, :n_sel].astype(jnp.int32)
+        out[leaf[0]] = jnp.argsort(u, axis=-1)[:, 0, :n_sel].astype(jnp.int32)
     return out
 
 
 def gather_blocks(w, idx, block: int):
-    """w [in, out] -> the selected blocks [in, n_sel, block]."""
-    wb = w.reshape(w.shape[0], -1, block)
-    return jnp.take(wb, idx, axis=1)
+    """w [..., out] -> the selected blocks [..., n_sel, block]; every
+    leading axis (fan-in, experts) is kept."""
+    wb = w.reshape(w.shape[:-1] + (-1, block))
+    return jnp.take(wb, idx, axis=-2)
 
 
 def set_blocks(w, idx, vals, block: int):
-    wb = w.reshape(w.shape[0], -1, block)
-    return wb.at[:, idx, :].set(vals.astype(w.dtype)).reshape(w.shape)
+    wb = w.reshape(w.shape[:-1] + (-1, block))
+    return wb.at[..., idx, :].set(vals.astype(w.dtype)).reshape(w.shape)
 
 
 def rule(opt: dict, t, p, g, mu, nu):

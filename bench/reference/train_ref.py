@@ -2,17 +2,33 @@
 random block selections as the benchmark gives the program, followed
 through the first steps in float32 (or in the fp8 control mode).
 
-Per step: the forward of the frozen prefix layer by layer, the loss of the
-trainable suffix and the head, its gradient at the selected channel blocks
-of every selectable weight and at every other leaf of the suffix, and the
-optimizer rule on exactly those values; the weights are stored back in
-their configured dtype. The embedding, head and final norm stay frozen.
+Per step: the forward of the frozen prefix layer by layer, through every
+segment of the program in order; the loss of the trainable suffix (the
+last `update_layers` layers of the last segment) and the head, its
+gradient at the selected channel blocks of every selectable weight (one
+block draw per layer, shared by all experts of an expert weight) and at
+every other leaf of the suffix, and the optimizer rule on exactly those
+values; the weights are stored back in their configured dtype. The
+embedding, head and final norm stay frozen.
+
+A reference module gives `leaf_specs`, `selectable_leaves`, `embed`,
+`layer`, `head_weight` and `flops_per_token`, and either `SEGMENT` (one
+segment of all `num_layers` layers) or `segments(m)`, the program's
+segments in order as [(name, layers)], with `LAYERS`, the layer function
+of each segment by name. A module whose loss has terms over the whole
+batch (a router's balance loss) gives `aux_loss(m, sums, tokens)`: its
+layer functions then return (x, sums), each a sum over the row's tokens,
+and the loss is the rows' mean cross-entropy plus `aux_loss` of every
+layer's sums over the whole batch. The rows go one at a time, so the
+gradient takes `aux_loss` linearised at the batch's sums: exact, since
+its gradient is a sum over rows of each row's sums' Jacobian times the
+same cotangent.
 
 Readings, keyed by the leaf's path under the segment ("time/wr"):
   losses        the loss of each step;
   grad_norms    per leaf, the norm of the first step's gradient as the
                 optimizer holds it after that step (AdamW: mu / (1 - beta1);
-                SGD: (w0 - w1) / lr);
+                SGD: (w0 - w1) / lr), over the whole leaf;
   change_norms  per leaf, the norm of w_n - w_0 after the n steps.
 """
 from __future__ import annotations
@@ -60,22 +76,45 @@ def make_params(ref, m):
     return init
 
 
+def segments(ref, m) -> list:
+    """[(name, layers)] of the program's segments in order."""
+    if hasattr(ref, "segments"):
+        return ref.segments(m)
+    return [(ref.SEGMENT, m["num_layers"])]
+
+
+def segment_layer(ref, name):
+    """The module's layer function for the segment `name`."""
+    return getattr(ref, "LAYERS", {}).get(name, getattr(ref, "layer", None))
+
+
+def suffix_segment(ref, m, k: int) -> tuple:
+    """(name, index of the first trainable layer) of the last segment, which
+    holds the trainable suffix: its last k layers."""
+    name, n = segments(ref, m)[-1]
+    if n < k:
+        raise SystemExit(f"bench: {k} trainable layers asked for, but the "
+                         f"last segment {name!r} holds {n}; the reference "
+                         f"trains within the last segment only")
+    return name, n - k
+
+
 def seg_paths(ref, m):
-    """Leaf paths within one layer of the segment, sorted."""
-    n = len(("segments", ref.SEGMENT))
-    return sorted(p[n:] for p in ref.leaf_specs(m)
-                  if p[:n] == ("segments", ref.SEGMENT))
+    """Leaf paths within one layer of the last segment, sorted."""
+    seg = ("segments", segments(ref, m)[-1][0])
+    n = len(seg)
+    return sorted(p[n:] for p in ref.leaf_specs(m) if p[:n] == seg)
 
 
 def key_of(path) -> str:
     return "/".join(path)
 
 
-def layer_at(ref, m, blocks, i, x, mode):
+def layer_at(layer, m, blocks, i, x, mode):
     """Layer i of a segment's stacked weights applied to x."""
     get = lambda *p: jax.lax.dynamic_index_in_dim(_get(blocks, p), i,
                                                   keepdims=False)
-    return ref.layer(m, get, x, mode)
+    return layer(m, get, x, mode)
 
 
 class Reference:
@@ -87,34 +126,59 @@ class Reference:
         self.ratio = traffic["update_ratio"]
         self.block_req = traffic["channel_block"]
         self.opt = traffic["optimizer"]
+        self.segs = segments(ref, m)
+        self.seg, self.first = suffix_segment(ref, m, self.k)
+        self.aux = hasattr(ref, "aux_loss")
         self.sel_leaves = ref.selectable_leaves(m)
-        self.sel_paths = {p for p, _i, _o in self.sel_leaves}
-        self.blocks = {p: C.sel_spec(o, self.ratio, self.block_req)[0]
-                       for p, _i, o in self.sel_leaves}
+        self.sel_paths = {leaf[0] for leaf in self.sel_leaves}
+        self.blocks = {leaf[0]: C.sel_spec(leaf[2], self.ratio,
+                                           self.block_req)[0]
+                       for leaf in self.sel_leaves}
         self.paths = seg_paths(ref, m)
         self._embed = jax.jit(partial(ref.embed, mode=mode))
-        self._layer = jax.jit(partial(layer_at, ref, m, mode=mode))
+        self._layers = {name: jax.jit(partial(
+            layer_at, segment_layer(ref, name), m, mode=mode))
+            for name, _n in self.segs}
         self._grad = jax.jit(jax.value_and_grad(self._suffix_loss,
-                                                argnums=(0, 1)))
+                                                argnums=(0, 1), has_aux=True))
+        self._sums = jax.jit(lambda *a: self._suffix(*a)[1])
         self._update = jax.jit(self._apply, donate_argnums=(0, 1))
 
     # -- program pieces ----------------------------------------------------
-    def _suffix_loss(self, deltas, dense, train, sel, x, labels, frozen):
+    def _suffix(self, deltas, dense, train, sel, x):
+        """The trainable layers on x: (their output, each layer's sums)."""
+        layer = segment_layer(self.ref, self.seg)
+
         def one(raw, idx, dl, dn, x):
             def get(*p):
                 if p in self.sel_paths:
                     return C.Sel(raw[p], idx[p], dl[p], self.blocks[p])
                 return dn[p]
-            return self.ref.layer(self.m, get, x, self.mode)
+            return layer(self.m, get, x, self.mode)
 
+        sums = []
         for j in range(self.k):
             pick = lambda t: {p: v[j] for p, v in t.items()}
-            x = jax.checkpoint(one)(pick(train), pick(sel), pick(deltas),
-                                    pick(dense), x)
-        h = C.layernorm(frozen["final_norm"], x)
+            out = jax.checkpoint(one)(pick(train), pick(sel), pick(deltas),
+                                      pick(dense), x)
+            x, s = out if self.aux else (out, None)
+            sums.append(s)
+        return x, sums
+
+    def _suffix_loss(self, deltas, dense, train, sel, x, labels, frozen,
+                     g_sums):
+        """(objective, cross-entropy) of one row: the objective adds the
+        row's sums against g_sums, the cotangent of the batch's aux loss."""
+        x, sums = self._suffix(deltas, dense, train, sel, x)
+        h = C.norm(frozen["final_norm"], x)
         d = h.shape[-1]
-        return C.mean_cross_entropy(h.reshape(-1, d), frozen["head"],
-                                    labels.reshape(-1), self.mode)
+        ce = C.mean_cross_entropy(h.reshape(-1, d), frozen["head"],
+                                  labels.reshape(-1), self.mode)
+        if g_sums is None:
+            return ce, ce
+        lin = sum(jnp.vdot(g, v) for g, v in zip(jax.tree.leaves(g_sums),
+                                                 jax.tree.leaves(sums)))
+        return ce + lin, ce
 
     def _apply(self, train, state, g_sel, g_dense, sel, t):
         """The optimizer rule on the selected blocks and the dense leaves;
@@ -155,11 +219,12 @@ class Reference:
         """Follow `n_steps` steps on `batches` (host dicts of tokens and
         labels). `rows` keeps only the first rows of each batch (a planted
         fault: part of the batch left out)."""
-        L = self.m["num_layers"]
-        first = L - self.k
-        blocks = params["segments"][self.ref.SEGMENT]
-        w0 = lambda: {p: _get(blocks, p)[first:] for p in self.paths}
-        train = w0()
+        stacks = params["segments"]
+        w0 = lambda: {p: _get(stacks[self.seg], p)[self.first:]
+                      for p in self.paths}
+        # a copy: the update donates it, and where the whole segment trains
+        # the slice is the params' own array
+        train = jax.tree.map(jnp.copy, w0())
         frozen = {"final_norm": params["final_norm"],
                   "head": self.ref.head_weight(params)}
         adam = self.opt["kind"] == "adamw"
@@ -171,30 +236,37 @@ class Reference:
         for step in range(n_steps):
             tok = batches[step]["tokens"][:rows]
             lab = batches[step]["labels"][:rows]
-            sel = C.draw_selection(state_key, step, self.ref.SEGMENT,
+            sel = C.draw_selection(state_key, step, self.seg,
                                    self.sel_leaves, self.k, self.ratio,
                                    self.block_req)
-            sel = {p: sel[p] for p in self.sel_paths}
-            deltas = {p: jnp.zeros((self.k, i, sel[p].shape[-1],
-                                    self.blocks[p]), jnp.float32)
-                      for p, i, _o in self.sel_leaves}
+            deltas = {leaf[0]: jnp.zeros(self._delta_shape(
+                leaf, sel[leaf[0]].shape[-1]), jnp.float32)
+                for leaf in self.sel_leaves}
             dense = {p: v.astype(jnp.float32) for p, v in train.items()
                      if p not in self.sel_paths}
             # row by row, so that the activations of one row fit beside
             # the weights; every row has the same number of tokens, so the
             # batch's mean loss and gradient are the mean over rows
             n = tok.shape[0]
+            xs, frozen_sums = [], None
+            for r in range(n):
+                x, sums = self._frozen(params, tok[r:r + 1])
+                xs.append(x)
+                if self.aux:
+                    frozen_sums = sums if r == 0 else _add(frozen_sums, sums)
+            g_sums, aux = None, 0.0
+            if self.aux:
+                g_sums, aux = self._aux(deltas, dense, train, sel, xs,
+                                        frozen_sums, n * tok.shape[1])
             loss, grads = 0.0, None
             for r in range(n):
-                x = self._embed(params, jnp.asarray(tok[r:r + 1]))
-                for i in range(first):
-                    x = self._layer(blocks, jnp.int32(i), x)
-                lr, g = self._grad(deltas, dense, train, sel, x,
-                                   jnp.asarray(lab[r:r + 1]), frozen)
-                loss += float(lr) / n
+                (_, ce), g = self._grad(deltas, dense, train, sel, xs[r],
+                                        jnp.asarray(lab[r:r + 1]), frozen,
+                                        g_sums)
+                loss += float(ce) / n
                 grads = g if grads is None else _add(grads, g)
             g_sel, g_dense = _scale(grads, 1.0 / n)
-            out["losses"].append(loss)
+            out["losses"].append(loss + aux)
             train, state = self._update(train, state, g_sel, g_dense, sel,
                                         jnp.float32(step + 1))
             if step == 0:
@@ -202,6 +274,38 @@ class Reference:
                                                      state)
         out["change_norms"] = change_norms(w0(), train)
         return out
+
+    def _delta_shape(self, leaf, n_sel: int) -> tuple:
+        """[k(, experts), in, n_sel, block]: the offsets of a selectable
+        leaf's selected blocks in the trainable layers."""
+        e = C.leaf_experts(leaf)
+        return ((self.k,) + ((e,) if e else ())
+                + (leaf[1], n_sel, self.blocks[leaf[0]]))
+
+    def _frozen(self, params, tokens):
+        """The embedding and the frozen prefix, every segment in order, on
+        rows of tokens: (x, each layer's sums)."""
+        stacks = params["segments"]
+        x = self._embed(params, jnp.asarray(tokens))
+        sums = []
+        for name, count in self.segs:
+            for i in range(self.first if name == self.seg else count):
+                y = self._layers[name](stacks[name], jnp.int32(i), x)
+                x, s = y if self.aux else (y, None)
+                sums.append(s)
+        return x, sums
+
+    def _aux(self, deltas, dense, train, sel, xs, frozen_sums, tokens):
+        """(cotangent of the aux loss by each trainable layer's sums, scaled
+        by the rows as the mean over rows takes it back; the aux loss)."""
+        sums = None
+        for x in xs:
+            s = self._sums(deltas, dense, train, sel, x)
+            sums = s if sums is None else _add(sums, s)
+        value, grad = jax.value_and_grad(
+            lambda t: self.ref.aux_loss(self.m, frozen_sums + t, tokens))(
+            sums)
+        return _scale(grad, float(len(xs))), float(value)
 
 
 @jax.jit

@@ -78,6 +78,30 @@ def _clip(e, lo, hi):
     return (s, t) if t > s else None
 
 
+def self_times(ops: list, lo: int, hi: int) -> list:
+    """(op, self ns) for the ops of one device line: each op's duration
+    clipped to [lo, hi) less the union of the clipped ops nested wholly
+    inside it (a `while` holds its body's ops)."""
+    ops = sorted(ops, key=lambda e: (e["start_ns"], -e["dur_ns"]))
+    kids = [[] for _ in ops]
+    open_ = []
+    for i, e in enumerate(ops):
+        end = e["start_ns"] + e["dur_ns"]
+        while open_ and (ops[open_[-1]]["start_ns"]
+                         + ops[open_[-1]]["dur_ns"]) < end:
+            open_.pop()
+        iv = _clip(e, lo, hi)
+        if open_ and iv is not None:
+            kids[open_[-1]].append(iv)
+        open_.append(i)
+    out = []
+    for e, inner in zip(ops, kids):
+        iv = _clip(e, lo, hi)
+        if iv is not None:
+            out.append((e, iv[1] - iv[0] - _union(inner)))
+    return out
+
+
 def _op_family(name: str) -> str:
     return re.sub(r"[.\-_]\d+$", "", name)
 
@@ -93,8 +117,10 @@ def reduce(events: list, window_ns: tuple, kernels: list,
     Returns busy_s (union of device op intervals, averaged over devices),
     window_s, kernel_s {kernel: seconds summed over devices}, steps (step
     programs that ran, summed over devices / devices), step_s (their mean
-    device duration), device_ops (the 10 op families with most time), and
-    idle_gaps (the 10 longest gaps with the host's innermost span in them).
+    device duration), device_ops (the 10 op families with most self time:
+    an op's time less that of the ops nested inside it, so a `while` does
+    not count its body again), and idle_gaps (the 10 longest gaps with the
+    host's innermost span in them).
     """
     lo, hi = window_ns
     devices = sorted({e["plane"] for e in events
@@ -102,7 +128,7 @@ def reduce(events: list, window_ns: tuple, kernels: list,
     busy, kern, fam = 0.0, {k: 0.0 for k in kernels}, {}
     step_durs, gaps = [], []
     for dev in devices:
-        ops = []
+        ops, dev_ops = [], []
         for e in events:
             if e["plane"] != dev:
                 continue
@@ -114,15 +140,17 @@ def reduce(events: list, window_ns: tuple, kernels: list,
             if iv is None:
                 continue
             ops.append(iv)
+            dev_ops.append(e)
             d = iv[1] - iv[0]
-            f = _op_family(e["name"])
-            fam[f] = fam.get(f, 0.0) + d
             for k in kernels:
                 if k in e["name"] or re.search(rf"\b{re.escape(k)}\b",
                                                e["text"]):
                     kern[k] += d
                     break
         busy += _union(ops)
+        for e, t in self_times(dev_ops, lo, hi):
+            f = _op_family(e["name"])
+            fam[f] = fam.get(f, 0.0) + t
         end = lo
         for s, t in sorted(ops):
             if s > end:

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from bench.reference import dense_lm, rwkv6
+from bench.reference import dense_lm, moe_lm, rwkv6
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -49,6 +49,25 @@ def test_dense_lm_flops_per_token_by_hand():
     # (4 * 2*64*16), w_up 2 of 8 (2*64*32), w_down 2*128*16; * 2 layers
     assert got == {"forward": 205184, "backward": 143872 + 32768,
                    "total": 381824}
+
+
+def test_moe_lm_flops_per_token_by_hand():
+    m = {"d_model": 64, "d_ff": 32, "vocab_size": 256, "num_layers": 3,
+         "num_heads": 4, "num_kv_heads": 2,
+         "moe": {"num_experts": 8, "top_k": 2, "num_shared_experts": 2,
+                 "layout": "all_but_first"}}
+    got = moe_lm.flops_per_token(m, seq=32, k_train=2, ratio=0.2,
+                                 block_req=16)
+    # attention weights 2*64*64 + 2*64*32 = 12288, core 2*2*(33/2)*64 = 4224
+    # dense layer: + 3*64*256 (width 8*32) = 61440; expert layer: + router
+    # 64*8 + 2 routed and 2 shared SwiGLUs 4*3*64*32 = 37376
+    # forward: (2*61440 + 4224) + 2*(2*37376 + 4224) + head 2*64*256
+    # = 317824; input grads: 2*(2*37376 + 2*4224) - 2*(64*64 + 2*64*32)
+    # + 32768 = 182784; weight grads per layer: router 2*64*8, wq/wk/wv/wo
+    # one block of 16 each 4*2*64*16, routed w_gate/w_up 2 experts each
+    # 2*(2*2*64*16), w_down 2*2*32*16, shared 3*2*64*16 = 25600; * 2 layers
+    assert got == {"forward": 317824, "backward": 182784 + 51200,
+                   "total": 551808}
 
 
 @pytest.mark.parametrize("name,call,want", [

@@ -1,8 +1,8 @@
 """The compiled train step of each training cell, at the tiny size, names
 its layers: every op carries its `jax.named_scope` path in its `op_name`
-metadata. bench/trace_scopes.py reads it from the step compiled again
-after a traced run, so that compile has to give the job's program, op for
-op."""
+metadata. bench/trace_scopes.py reads it from the text of the step the run
+compiled and ran (`compiled.as_text()`), which the job hands to the
+readers, so that text has to carry it."""
 import importlib
 import re
 
@@ -23,9 +23,9 @@ def test_compiled_step_names_its_layers(cell):
         f"bench.reference.{name}")}, t["entry"], t["mix"])
     assert job.tc.compact_grads
     job.build(2**31 + 7)
-    text = S.program_text(t["entry"], t["mix"])
+    text = job.step.as_text()
     ops = S.instructions(text)
-    assert ops == S.instructions(job.step.as_text())
+    assert ops
 
     names = [n for _head, n in ops.values()]
     seen = set().union(*(S.components(n) for n in names))

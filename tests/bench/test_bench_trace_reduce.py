@@ -31,3 +31,19 @@ def test_handmade_trace_known_times():
     assert red["device_ops"][:2] == [["convolution", pytest.approx(15e-6)],
                                      ["fusion", pytest.approx(10.5e-6)]]
 
+
+
+def test_device_ops_count_self_time():
+    """A `while` and the body ops nested in it are counted once: the listed
+    ops' seconds come to no more than the busy time (here, with every op
+    family listed, to exactly it)."""
+    events = json.load(open(os.path.join(DATA, "trace_events_scoped.json")))
+    span = T.host_span(events, "bench_window")
+    red = T.reduce(events, span, [], "train_step")
+    listed = sum(s for _name, s in red["device_ops"])
+    assert listed <= red["busy_s"] * (1 + 1e-12)
+    assert listed == pytest.approx(red["busy_s"])
+    ops = [e for e in events if e["line"] == "XLA Ops"]
+    whole = sum(T._clip(e, *span)[1] - T._clip(e, *span)[0] for e in ops
+                if T._clip(e, *span)) / 1e9
+    assert whole > red["busy_s"]       # what whole durations would list

@@ -46,7 +46,7 @@ def test_handmade_scopes_known_self_times():
     events = _events()
     span = TRD.host_span(events, "bench_window")
     assert span == (1000, 51000)
-    got = S.scopes(events, span, S.NAMES)
+    got = S.tally(S.op_times(events, span), S.NAMES)
     # while 2000..22000 holds 6 us of token_mix and 8 us of channel_mix:
     # 6 us of its own; the backward conv 10 us; fusion.7 clipped to 3 us;
     # ops outside the window count nothing
@@ -146,9 +146,10 @@ def trace_on_disk(tmp_path, monkeypatch):
     monkeypatch.setattr(S, "ROOT", str(tmp_path))
     monkeypatch.setattr(S.TRD, "load_events",
                         lambda _d: _trace_form(now["events"]))
-    monkeypatch.setattr(S, "program_text", lambda entry, mix: now["hlo"])
     monkeypatch.setattr(S, "_READ", {})
-    return put
+    return put, lambda window_s=50e-6: {
+        "trace": {"steps": 2, "window_s": window_s},
+        "program_text": now["hlo"]}
 
 
 @pytest.mark.parametrize("metric,us", [
@@ -156,22 +157,38 @@ def trace_on_disk(tmp_path, monkeypatch):
     ("head_loss_ms.train", 3), ("token_mix_ms.train", 16),
     ("update_ms.train", 4)])
 def test_readers_give_device_ms_per_step(trace_on_disk, metric, us):
-    trace_on_disk(_events(), _hlo(_events()))
-    ctx = {"trace": {"steps": 2, "window_s": 50e-6}}
-    assert _reader(metric).read(ctx) == pytest.approx(us * 1e-3 / 2)
+    put, ctx = trace_on_disk
+    put(_events(), _hlo(_events()))
+    assert _reader(metric).read(ctx()) == pytest.approx(us * 1e-3 / 2)
 
 
 def test_readers_read_nothing_without_scopes_or_of_another_window(
         trace_on_disk):
-    ctx = {"trace": {"steps": 2, "window_s": 50e-6}}
+    put, ctx = trace_on_disk
     bare = [dict(e, scope="") for e in _events()]
-    trace_on_disk(bare, _hlo(bare))        # a program without the scopes
+    put(bare, _hlo(bare))                  # a program without the scopes
     for metric in ("frozen_fwd_ms.train", "update_ms.train"):
-        assert _reader(metric).read(ctx) is None
-    trace_on_disk(_events(), _hlo(_events()).replace("f32[4,64]",
-                                                     "f32[8,64]"))
-    assert _reader("suffix_ms.train").read(ctx) is None   # another program
-    trace_on_disk(_events(), _hlo(_events()))
-    other = {"trace": {"steps": 2, "window_s": 40e-6}}
-    assert _reader("suffix_ms.train").read(other) is None
-    assert _reader("suffix_ms.train").read(ctx) == pytest.approx(5e-3)
+        assert _reader(metric).read(ctx()) is None
+    put(_events(), _hlo(_events()).replace("f32[4,64]", "f32[8,64]"))
+    assert _reader("suffix_ms.train").read(ctx()) is None  # another program
+    put(_events(), _hlo(_events()))
+    assert _reader("suffix_ms.train").read(ctx(40e-6)) is None
+    assert _reader("suffix_ms.train").read(ctx()) == pytest.approx(5e-3)
+    no_text = dict(ctx(), program_text=None)
+    assert _reader("suffix_ms.train").read(no_text) is None
+
+
+def test_step_ms_reads_any_scope_name(trace_on_disk):
+    """A scope outside the train step's eight, such as an expert layer's
+    `experts`, reads its ops' self time; a name no op carries reads
+    nothing."""
+    put, ctx = trace_on_disk
+    made = [dict(e, scope=e["scope"].replace("channel_mix",
+                                             "channel_mix/experts"))
+            if "scope" in e else e for e in _events()]
+    put(made, _hlo(made))
+    assert "experts" not in S.NAMES
+    assert S.step_ms(ctx(), ("experts",)) == pytest.approx(8e-3 / 2)
+    assert S.step_ms(ctx(), ("experts",)) == S.step_ms(ctx(),
+                                                        ("channel_mix",))
+    assert S.step_ms(ctx(), ("router",)) is None
