@@ -22,8 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.batched_dw import (batched_dw_kernel,
-                                      batched_dw_pipelined_kernel)
+from repro.kernels.batched_dw import batched_dw_kernel
 from repro.kernels.masked_dw import (block_sparse_dw_kernel,
                                      block_sparse_dw_pipelined_kernel)
 from repro.kernels.scatter_blocks import block_scatter_update_kernel
@@ -93,9 +92,8 @@ def run() -> list[tuple]:
 
 def batched_dw_comparison() -> list[tuple]:
     """MoE expert-batched compact dW: the single-launch `batched_dw` kernel
-    (grid spans experts x shards x selected blocks) vs the per-expert
-    loop-of-launches it replaces, plus the double-buffered `emit_pipeline`
-    variants of both dW kernels. Same eager-dispatch timing discipline as
+    over rows grouped by expert (grid spans shards x selected blocks x
+    each expert's row tiles) vs a loop of one launch per expert. Same eager-dispatch timing discipline as
     `fusion_comparison` (each un-jitted pallas_call pays a full dispatch —
     the cost the batching removes); launch-site counts are exact on any
     backend."""
@@ -104,23 +102,21 @@ def batched_dw_comparison() -> list[tuple]:
     e, m, k, s, nb, blk = 4, 64, 64, 2, 8, 16
     n_sel = 2                                   # ratio 0.25
     n = s * nb * blk
-    x = jnp.asarray(rng.normal(size=(e, m, k)), jnp.float32)
-    dy = jnp.asarray(rng.normal(size=(e, m, n)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(e * m, k)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(e * m, n)), jnp.float32)
+    sizes = jnp.full((e,), m, jnp.int32)
     idx = jnp.asarray(
         np.stack([rng.choice(nb, n_sel, replace=False) for _ in range(s)]),
         jnp.int32)
 
     def dw_batched(x, dy, idx):
-        return batched_dw_kernel(x, dy, idx, block=blk, tm=m, tk=k,
+        return batched_dw_kernel(x, dy, sizes, idx, block=blk, tm=m, tk=k,
                                  interpret=True)
 
-    def dw_batched_pipelined(x, dy, idx):
-        return batched_dw_pipelined_kernel(x, dy, idx, block=blk, tm=32,
-                                           tk=k, interpret=True)
-
-    def dw_per_expert_loop(x, dy, idx):         # pre-PR: one launch/expert
-        outs = [block_sparse_dw_kernel(x[ei], dy[ei], idx, block=blk,
-                                       tm=m, tk=k, interpret=True)
+    def dw_per_expert_loop(x, dy, idx):         # one launch per expert
+        outs = [block_sparse_dw_kernel(x[ei * m:(ei + 1) * m],
+                                       dy[ei * m:(ei + 1) * m], idx,
+                                       block=blk, tm=m, tk=k, interpret=True)
                 for ei in range(e)]
         return jnp.stack(outs)
 
@@ -129,7 +125,6 @@ def batched_dw_comparison() -> list[tuple]:
     rl = kernel_roofline(2.0 * e * m * k * sel,
                          4.0 * e * (m * k + m * sel + k * sel))
     for variant, fn in (("fused", dw_batched),
-                        ("pipelined", dw_batched_pipelined),
                         ("per_expert_loop", dw_per_expert_loop)):
         us = _time(fn, x, dy, idx, n=3)          # eager: dispatch per launch
         launches = _launches(fn, x, dy, idx)
@@ -144,8 +139,8 @@ def batched_dw_comparison() -> list[tuple]:
         return block_sparse_dw_pipelined_kernel(x2, dy2, idx, block=blk,
                                                 tm=32, tk=k, interpret=True)
 
-    us = _time(dw_pipelined, x[0], dy[0], idx, n=3)
-    launches = _launches(dw_pipelined, x[0], dy[0], idx)
+    us = _time(dw_pipelined, x[:m], dy[:m], idx, n=3)
+    launches = _launches(dw_pipelined, x[:m], dy[:m], idx)
     rows.append(("kernel/dw_pipelined", us,
                  f"launches={launches};eager_dispatch"))
     RECORDS.append({"op": "masked_dw", "variant": "pipelined",

@@ -18,12 +18,34 @@ from typing import Optional, Sequence
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int              # routed experts
+    """A mixture-of-experts layer, or this chip's share of one.
+
+    The router scores all `num_routed_experts`; the layer holds
+    `num_experts` of them, from global index `first_expert` on, and returns
+    only their part of the routed output (plus the shared experts). Under a
+    mesh the held experts are further split over the model axis."""
+    num_experts: int              # routed experts held here
     top_k: int
     num_shared_experts: int = 0   # always-on experts (deepseek/llama4 style)
+    # not read: dispatch is dropless; kept so configuration files that name
+    # it still load
     capacity_factor: float = 1.25
     # which layers are MoE: "all", "every_2", "all_but_first"
     layout: str = "all"
+    num_routed_experts: int = 0   # the router's width; 0: num_experts
+    first_expert: int = 0         # global index of the first held expert
+    # "softmax": top-k of softmax(x Wr), renormalised, switch-style batch
+    # balance loss; "sigmoid" (DeepSeek-V3): top-k of sigmoid(x Wr) + bias,
+    # weights renormalised over the top-k, sequence-wise balance loss
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0   # factor on the routed experts' weights
+    dense_d_ff: int = 0           # the leading dense layer's width; 0: 8 d_ff
+    aux_weight: float = 0.01      # balance loss weight
+    z_weight: float = 1e-3        # router z-loss weight
+
+    @property
+    def routed(self) -> int:
+        return self.num_routed_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -67,6 +89,15 @@ class ModelConfig:
     embed_inputs: bool = False
     # M-RoPE (qwen2-vl): rope over 3 position coordinates
     mrope: bool = False
+    # multi-head latent attention (DeepSeek-V2/V3), on when kv_lora_rank > 0:
+    # q = x Wq; [c_kv | k_pe] = x Wkv_a, c_kv RMS-normed; [k_nope | v] =
+    # c_kv Wkv_b per head; k_pe one RoPE head shared by all heads
+    kv_lora_rank: int = 0
+    q_lora_rank: Optional[int] = None   # a query LoRA is not built
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    norm_eps: float = 1e-6
     dtype: str = "bfloat16"
 
     # -- derived ---------------------------------------------------------
@@ -76,6 +107,10 @@ class ModelConfig:
             return self.head_dim
         assert self.num_heads > 0
         return self.d_model // self.num_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def attn_free(self) -> bool:
@@ -125,6 +160,7 @@ ARCH_IDS = (
     "jamba-1.5-large-398b",
     "qwen2-vl-7b",
     "rwkv6-3b",
+    "moonlight-16b-a3b",
 )
 
 _MODULES = {
@@ -139,6 +175,7 @@ _MODULES = {
     "qwen2-vl-7b": "qwen2_vl_7b",
     "rwkv6-3b": "rwkv6_3b",
     "mobilenetv2-cifar": "mobilenetv2_cifar",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 
@@ -156,6 +193,9 @@ def cell_is_skipped(arch_id: str, shape_name: str) -> Optional[str]:
     """Return a skip-reason string if (arch, shape) is not runnable."""
     if shape_name == "long_500k" and arch_id not in LONG_CONTEXT_ARCHS:
         return "pure full-attention arch: no sub-quadratic path for 500k decode"
+    if SHAPES[shape_name].kind != "train" and get_config(arch_id).mla:
+        return ("latent attention (MLA) has no KV cache in this program: "
+                "prefill and decode are not built for it")
     return None
 
 

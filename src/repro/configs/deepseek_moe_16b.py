@@ -1,9 +1,7 @@
 """deepseek-moe-16b [moe]: 28L d_model=2048 16H (GQA kv=16) d_ff=1408 vocab=102400.
 
 MoE: 2 shared + 64 routed experts, top-6, fine-grained [arXiv:2401.06066; hf].
-First layer is a dense FFN (d_ff dense = 64*1408/ ... deepseek uses 10944
-dense first layer; we use num_experts*d_ff-equivalent? Faithful: dense first
-layer with d_ff_dense = 10944).
+The first layer is a dense SwiGLU of width 10944.
 """
 from repro.configs.base import ModelConfig, MoEConfig
 
@@ -20,7 +18,7 @@ CONFIG = ModelConfig(
     norm_kind="rmsnorm",
     rope_theta=10_000.0,
     moe=MoEConfig(num_experts=64, top_k=6, num_shared_experts=2,
-                  layout="all_but_first"),
+                  layout="all_but_first", dense_d_ff=10_944),
 )
 
 

@@ -29,7 +29,7 @@ from repro.sharding import current_rules
 
 # weight leaves that participate in channel selection (out-channel blocks)
 SELECTABLE = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-              "in_proj", "out_proj", "wg", "wr"}
+              "in_proj", "out_proj", "wg", "wr", "wkv_a", "wkv_b"}
 # excluded even though matmul-shaped: tiny recurrence/router params
 EXCLUDED = {"router", "x_proj", "dt_proj", "A_log", "wA", "wB", "mu", "u",
             "w0", "conv_w"}

@@ -35,13 +35,14 @@ the [*, n_shards, n_sel, block] layout:
    into a compact `w_sel` companion tensor; the train step differentiates
    w.r.t. `w_sel` while the full weight enters the forward matmul with its
    gradient stopped.
-2. `_smm_compact` / `_smm_batched_compact` compute the identical forward
-   `x @ w` but their VJP emits the compact `compact_dw` /
-   `compact_dw_batched` result directly as the cotangent of `w_sel` — no
-   zero buffer, no full-shape scatter. Under `use_kernels` both are single
-   Pallas launches (`kernels.masked_dw` for 2D weights,
+2. `_smm_compact` (`x @ w`) and `_gmm_compact` (an MoE layer's grouped
+   matmul: rows sorted by expert, each expert's rows times its own weight)
+   compute the identical forward but their VJP emits the compact
+   `compact_dw` / `compact_dw_batched` result directly as the cotangent of
+   `w_sel` — no zero buffer, no full-shape scatter. Under `use_kernels`
+   both are single Pallas launches (`kernels.masked_dw` for 2D weights,
    `kernels.batched_dw` for stacked expert weights: one grid over
-   experts x shards x selected blocks).
+   shards x selected blocks x each expert's row tiles).
 3. `repro.optim.apply_updates_mixed` clips, applies the SGD/momentum/AdamW
    rule on the gathered blocks (gathering the matching optimizer-state
    blocks), and writes the result back with `scatter_param_blocks` (or the
@@ -238,20 +239,24 @@ def compact_dw(x2, dy2, idx, spec: SelSpec):
                       preferred_element_type=jnp.float32)
 
 
-def compact_dw_batched(x3, dy3, idx, spec: SelSpec):
+def compact_dw_batched(x2, dy2, group_sizes, idx, spec: SelSpec):
     """Expert-batched compute skip: per-expert dW for selected blocks only.
 
-    x3: [E, C, K], dy3: [E, C, N] -> [E, K, n_shards, n_sel, block].
-    Under `use_kernels` this is ONE Pallas launch for all experts x shards x
-    selected blocks (`kernels.batched_dw`); the jnp fallback below is the
-    oracle the kernel is verified against."""
+    x2: [M, K], dy2: [M, N], rows sorted by expert in groups of
+    `group_sizes` [E] (rows past the last group belong to no expert) ->
+    [E, K, n_shards, n_sel, block]. Under `use_kernels` this is ONE Pallas
+    launch for all experts x shards x selected blocks
+    (`kernels.batched_dw`); the jnp fallback below is the oracle the kernel
+    is verified against."""
     if kernels_enabled():
         from repro.kernels import ops as kops
-        return kops.block_sparse_dw_batched(x3, dy3, idx, spec)
-    e, m, _ = x3.shape
-    dyb = dy3.reshape(e, m, spec.n_shards, spec.n_blocks, spec.block)
-    dy_sel = jnp.take_along_axis(dyb, idx[None, None, :, :, None], axis=3)
-    return jnp.einsum("eck,ecsnb->eksnb", x3, dy_sel,
+        return kops.block_sparse_dw_batched(x2, dy2, group_sizes, idx, spec)
+    m = x2.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(m)[None, :]
+    own = (rows >= (ends - group_sizes)[:, None]) & (rows < ends[:, None])
+    dy_sel = _gather_blocks(dy2, idx, spec)
+    return jnp.einsum("em,mk,msnb->eksnb", own.astype(x2.dtype), x2, dy_sel,
                       preferred_element_type=jnp.float32)
 
 
@@ -316,59 +321,117 @@ def smm(x, w, sel, name: str):
         kops.check_tpu_tiling(name, w.shape, spec)
     wsel_dict = sel[2] if len(sel) > 2 else None
     if wsel_dict is not None and name in wsel_dict:
-        if w.ndim == 2:
-            return _smm_compact(x, w, wsel_dict[name], idx, spec)
-        return _smm_batched_compact(x, w, wsel_dict[name], idx, spec)
-    if w.ndim == 2:
-        return _smm(x, w, idx, spec)
-    return _smm_batched(x, w, idx, spec)
+        return _smm_compact(x, w, wsel_dict[name], idx, spec)
+    return _smm(x, w, idx, spec)
 
 
-# batched (expert) variant: x [E, C, K], w [E, K, N]
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _smm_batched(x, w, idx, spec: SelSpec):
-    return jnp.einsum("eck,ekn->ecn", x, w)
+# grouped (expert) variant: x [M, K] rows sorted by expert in groups of
+# group_sizes [E], w [E, K, N]; expert e multiplies its own rows. Rows past
+# the last group come out unspecified (zero on the jnp path, unwritten by
+# the kernel): the caller masks them.
+
+def _gmm(x, w, group_sizes, transpose: bool = False):
+    """x's rows times their expert's w (w^T with `transpose`), in x's dtype
+    with float32 accumulation: the megablox Pallas kernel under
+    `use_kernels`, `jax.lax.ragged_dot` as the jnp oracle."""
+    if kernels_enabled():
+        from repro.kernels import ops as kops
+        return kops.grouped_matmul(x, w, group_sizes, transpose)
+    if transpose:
+        w = jnp.swapaxes(w, -1, -2)
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
 
 
-def _smmb_fwd(x, w, idx, spec):
-    return jnp.einsum("eck,ekn->ecn", x, w), (x, w, idx)
+def _gmm_dx(dy, w, group_sizes, x):
+    return _gmm(dy.astype(x.dtype), w, group_sizes, transpose=True)
 
 
-def _smmb_bwd(spec: SelSpec, res, dy):
-    x, w, idx = res
-    e, c, k = x.shape
-    n = w.shape[-1]
-    dx = jnp.einsum("ecn,ekn->eck", dy, w)
-    dw_sel = compact_dw_batched(x, dy, idx, spec)
+@jax.custom_vjp
+def _gmm_plain(x, w, group_sizes):
+    return _gmm(x, w, group_sizes)
+
+
+def _gmmp_fwd(x, w, group_sizes):
+    return _gmm(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _gmmp_bwd(res, dy):
+    x, w, gs = res
+    m = x.shape[0]
+    ends = jnp.cumsum(gs)
+    rows = jnp.arange(m)[None, :]
+    own = (rows >= (ends - gs)[:, None]) & (rows < ends[:, None])
+    dw = jnp.einsum("em,mk,mn->ekn", own.astype(x.dtype), x,
+                    dy.astype(x.dtype), preferred_element_type=jnp.float32)
+    return _gmm_dx(dy, w, gs, x), dw.astype(w.dtype), None
+
+
+_gmm_plain.defvjp(_gmmp_fwd, _gmmp_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm_sel(x, w, group_sizes, idx, spec: SelSpec):
+    return _gmm(x, w, group_sizes)
+
+
+def _gmms_fwd(x, w, group_sizes, idx, spec):
+    return _gmm(x, w, group_sizes), (x, w, group_sizes, idx)
+
+
+def _gmms_bwd(spec: SelSpec, res, dy):
+    x, w, gs, idx = res
+    e, k, n = w.shape
+    dw_sel = compact_dw_batched(x, dy, gs, idx, spec)
     zeros = jnp.zeros((e, k, spec.n_shards, spec.n_blocks, spec.block), w.dtype)
     dw = jnp.put_along_axis(
         zeros, jnp.broadcast_to(idx[None, None, :, :, None],
                                 (e, k, spec.n_shards, spec.n_sel, spec.block)),
         dw_sel.astype(w.dtype), axis=3, inplace=False).reshape(e, k, n)
-    return dx.astype(x.dtype), dw, None
+    return _gmm_dx(dy, w, gs, x), dw, None, None
 
 
-_smm_batched.defvjp(_smmb_fwd, _smmb_bwd)
+_gmm_sel.defvjp(_gmms_fwd, _gmms_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _smm_batched_compact(x, w, w_sel, idx, spec: SelSpec):
-    return jnp.einsum("eck,ekn->ecn", x, w)
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gmm_compact(x, w, w_sel, group_sizes, idx, spec: SelSpec):
+    return _gmm(x, w, group_sizes)
 
 
-def _smmbc_fwd(x, w, w_sel, idx, spec):
-    return jnp.einsum("eck,ekn->ecn", x, w), (x, w, idx)
+def _gmmc_fwd(x, w, w_sel, group_sizes, idx, spec):
+    return _gmm(x, w, group_sizes), (x, w, group_sizes, idx)
 
 
-def _smmbc_bwd(spec: SelSpec, res, dy):
-    x, w, idx = res
-    dx = jnp.einsum("ecn,ekn->eck", dy, w)
-    dw_sel = compact_dw_batched(x, dy, idx, spec)
-    return (dx.astype(x.dtype), jnp.zeros_like(w),
-            dw_sel.astype(w.dtype), None)
+def _gmmc_bwd(spec: SelSpec, res, dy):
+    x, w, gs, idx = res
+    dw_sel = compact_dw_batched(x, dy, gs, idx, spec)
+    return (_gmm_dx(dy, w, gs, x), jnp.zeros_like(w),
+            dw_sel.astype(w.dtype), None, None)
 
 
-_smm_batched_compact.defvjp(_smmbc_fwd, _smmbc_bwd)
+_gmm_compact.defvjp(_gmmc_fwd, _gmmc_bwd)
+
+
+def gmm(x, w, group_sizes, sel, name: str):
+    """Grouped matmul with channel-block-sparse dW: rows [offsets[e],
+    offsets[e + 1]) of x [M, K] times expert e's w[e] [K, N], where the
+    groups of `group_sizes` [E] follow each other from row 0; rows past the
+    last group come out zero. The expert-layer analogue of `smm`: `sel` as
+    there, with idx shared by all experts of the leaf; the weight gradient
+    (compact or scattered) comes from `compact_dw_batched` over the
+    groups, dX from the transposed grouped matmul."""
+    if sel is None or sel[0] is None or name not in sel[0]:
+        return _gmm_plain(x, w, group_sizes)
+    idx, spec = sel[0][name], sel[1][name]
+    if kernels_enabled():
+        from repro.kernels import ops as kops
+        kops.check_tpu_tiling(name, w.shape, spec)
+    wsel_dict = sel[2] if len(sel) > 2 else None
+    if wsel_dict is not None and name in wsel_dict:
+        return _gmm_compact(x, w, wsel_dict[name], group_sizes, idx, spec)
+    return _gmm_sel(x, w, group_sizes, idx, spec)
 
 
 # ---------------------------------------------------------------------------

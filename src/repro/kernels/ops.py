@@ -18,8 +18,7 @@ import jax.numpy as jnp
 
 from typing import Optional
 
-from repro.kernels.batched_dw import (batched_dw_kernel,
-                                      batched_dw_pipelined_kernel)
+from repro.kernels.batched_dw import batched_dw_kernel
 from repro.kernels.block_act_prune import block_act_prune_kernel
 from repro.kernels.masked_dw import (block_sparse_dw_kernel,
                                      block_sparse_dw_pipelined_kernel)
@@ -34,20 +33,45 @@ def _interpret() -> bool:
 LANES = 128
 
 
+def lanes() -> int:
+    """The lane multiple the kernels' channel blocks are padded to: the
+    vreg's 128 lanes when compiling for a TPU; 1 (no padding) in interpret
+    mode, whose tests use narrow blocks."""
+    return 1 if _interpret() else LANES
+
+
+def _padded(spec):
+    """spec with its channel block rounded up to whole `lanes()`: a 576-wide
+    latent projection's block of 96 rides in 128 lanes, zeros past 96."""
+    return spec._replace(block=-(-spec.block // lanes()) * lanes())
+
+
+def _pad_blocks(a, spec, pad):
+    """a [..., n_shards * n_blocks * block] (or [..., block] per block of a
+    compact layout) -> the same with every block zero-padded to pad.block."""
+    if pad.block == spec.block:
+        return a
+    ab = a.reshape(a.shape[:-1] + (-1, spec.block))
+    widths = [(0, 0)] * (ab.ndim - 1) + [(0, pad.block - spec.block)]
+    return jnp.pad(ab, widths).reshape(a.shape[:-1] + (-1,))
+
+
+def _unpad_blocks(a, spec, pad):
+    """The inverse of `_pad_blocks`: the first spec.block of every block."""
+    if pad.block == spec.block:
+        return a
+    ab = a.reshape(a.shape[:-1] + (-1, pad.block))[..., :spec.block]
+    return ab.reshape(a.shape[:-1] + (-1,))
+
+
 def check_tpu_tiling(leaf: str, w_shape, spec) -> None:
     """On a TPU backend, raise a readable error for a selectable leaf whose
-    compact-path kernels Mosaic would refuse: the channel block is the lane
-    dim of every dW / optimizer / writeback block, and the fan-in tile is
-    the lane dim of the dW kernels' activation block."""
+    compact-path dW kernel Mosaic would refuse: the fan-in tile is the lane
+    dim of the dW kernels' activation block. (A channel block that is not a
+    whole number of lanes is padded to one: `_padded`.)"""
     if jax.default_backend() != "tpu":
         return
-    k, n = w_shape[-2], w_shape[-1]
-    if spec.block % LANES:
-        raise ValueError(
-            f"leaf {leaf!r} (out width {n}): channel block {spec.block} is "
-            f"not a multiple of the TPU's {LANES} lanes; use a channel block "
-            f"of {LANES} (a width with no such divisor cannot take the "
-            f"compact-path kernels)")
+    k = w_shape[-2]
     tk = _pick_tile(k, LANES)
     if tk != k and tk % LANES:
         raise ValueError(
@@ -89,27 +113,63 @@ def block_sparse_dw(x2, dy2, idx, spec, pipelined: Optional[bool] = None):
     stripe residency).
     """
     m, k = x2.shape
+    pad = _padded(spec)
     tm, tk = _pick_tile(m, 128), _pick_tile(k, 128)
     kern = block_sparse_dw_pipelined_kernel if _use_pipelined(
-        m, tk, spec.block, x2.dtype.itemsize, pipelined) \
+        m, tk, pad.block, x2.dtype.itemsize, pipelined) \
         else block_sparse_dw_kernel
-    return kern(x2, dy2, idx, block=spec.block, tm=tm, tk=tk,
-                interpret=_interpret())
+    out = kern(x2, _pad_blocks(dy2, spec, pad), idx, block=pad.block, tm=tm,
+               tk=tk, interpret=_interpret())
+    return out[..., :spec.block]
 
 
-def block_sparse_dw_batched(x3, dy3, idx, spec, pipelined: Optional[bool] = None):
+def _lane_tile(k: int, cap: int) -> int:
+    """The whole of k up to `cap`, else its largest divisor <= 512 that is a
+    whole number of lanes (the dW kernels' fan-in tile)."""
+    if k <= cap:
+        return k
+    for d in range(512, 0, -LANES):
+        if k % d == 0:
+            return d
+    return _pick_tile(k, LANES)
+
+
+def block_sparse_dw_batched(x2, dy2, group_sizes, idx, spec):
     """Expert-batched compact dW (see core.sparse_update.compact_dw_batched).
 
-    x3: [E, C, K], dy3: [E, C, N], idx: [n_shards, n_sel] ->
-    [E, K, n_shards, n_sel, block] fp32, in ONE launch for all experts and
-    shards (the MoE expert leaf's whole backward is a single kernel)."""
-    e, m, k = x3.shape
-    tm, tk = _pick_tile(m, 128), _pick_tile(k, 128)
-    kern = batched_dw_pipelined_kernel if _use_pipelined(
-        m, tk, spec.block, x3.dtype.itemsize, pipelined) \
-        else batched_dw_kernel
-    return kern(x3, dy3, idx, block=spec.block, tm=tm, tk=tk,
-                interpret=_interpret())
+    x2: [M, K], dy2: [M, N] with rows sorted by expert in groups of
+    `group_sizes` [E], idx: [n_shards, n_sel] ->
+    [E, K, n_shards, n_sel, block], in ONE launch for all experts and
+    shards (the MoE expert leaf's whole backward is a single kernel). Rows
+    go in tiles of up to 512 and the whole fan-in up to 2048 in one tile,
+    so a step holds a [512, 2048] activation tile and does a 512-row
+    matmul, and the steps are few."""
+    m, k = x2.shape
+    pad = _padded(spec)
+    out = batched_dw_kernel(x2, _pad_blocks(dy2, spec, pad), group_sizes, idx,
+                            block=pad.block, tm=_pick_tile(m, 512),
+                            tk=_lane_tile(k, 2048), interpret=_interpret())
+    return out[..., :spec.block]
+
+
+def _matmul_tile(d: int) -> int:
+    """A grouped matmul's tile along a contraction or output dim: the whole
+    dim up to 1536 (1408-wide experts in one tile), else 512."""
+    return d if d <= 1536 else _pick_tile(d, 512)
+
+
+def grouped_matmul(x, w, group_sizes, transpose: bool = False):
+    """Rows of x [M, K], in consecutive groups of `group_sizes` [E], times
+    their group's w [E, K, N] (w [E, N, K] transposed with `transpose`), in
+    x's dtype with float32 accumulation: the megablox grouped matmul, whose
+    grid visits only the row tiles the groups cover, so the work follows
+    the live rows and not M. Rows past the last group are left unwritten."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    m, k = x.shape
+    n = w.shape[1] if transpose else w.shape[2]
+    return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+               tiling=(_pick_tile(m, 512), _matmul_tile(k), _matmul_tile(n)),
+               transpose_rhs=transpose, interpret=_interpret())
 
 
 def block_scatter_update(w, vals, idx, spec):
@@ -134,11 +194,13 @@ def block_scatter_update(w, vals, idx, spec):
     r = 1
     for d in w.shape[1:-1]:
         r *= d
-    w3 = w.reshape(k, r, n)
-    v5 = vals.reshape(k, r, spec.n_shards, spec.n_sel, spec.block)
+    pad = _padded(spec)
+    w3 = _pad_blocks(w.reshape(k, r, n), spec, pad)
+    v5 = _pad_blocks(vals, spec, pad).reshape(k, r, spec.n_shards,
+                                              spec.n_sel, pad.block)
     out = block_scatter_update_kernel(w3, v5, idx, tr=_pick_tile(r),
                                       interpret=_interpret())
-    return out.reshape(w.shape)
+    return _unpad_blocks(out, spec, pad).reshape(w.shape)
 
 
 def fused_block_optimizer(oc, p, g_sel, idx, spec, mu, nu, lr, t):
@@ -164,18 +226,19 @@ def fused_block_optimizer(oc, p, g_sel, idx, spec, mu, nu, lr, t):
     r = 1
     for d in p.shape[1:-1]:
         r *= d
-    p3 = p.reshape(k, r, n)
-    g5 = g_sel.reshape(k, r, spec.n_shards, spec.n_sel, spec.block)
-    mu3 = mu.reshape(k, r, n) if mu is not None else None
-    nu3 = nu.reshape(k, r, n) if nu is not None else None
+    pad = _padded(spec)
+    flat = lambda a: None if a is None else _pad_blocks(a.reshape(k, r, n),
+                                                        spec, pad)
+    back = lambda a: None if a is None else _unpad_blocks(
+        a, spec, pad).reshape(p.shape)
+    g5 = _pad_blocks(g_sel, spec, pad).reshape(k, r, spec.n_shards,
+                                               spec.n_sel, pad.block)
     w_new, mu_new, nu_new = fused_block_opt_kernel(
-        p3, g5, idx, lr, t, mu3, nu3, kind=kind, momentum=oc.momentum,
-        beta1=oc.beta1, beta2=oc.beta2, eps=oc.eps,
+        flat(p), g5, idx, lr, t, flat(mu), flat(nu), kind=kind,
+        momentum=oc.momentum, beta1=oc.beta1, beta2=oc.beta2, eps=oc.eps,
         weight_decay=oc.weight_decay, tr=_pick_tile(r),
         interpret=_interpret())
-    return (w_new.reshape(p.shape),
-            mu_new.reshape(p.shape) if mu_new is not None else None,
-            nu_new.reshape(p.shape) if nu_new is not None else None)
+    return back(w_new), back(mu_new), back(nu_new)
 
 
 def block_act_prune(x, threshold: float = 0.15, block: int = 2):

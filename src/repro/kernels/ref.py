@@ -16,17 +16,22 @@ def block_sparse_dw_ref(x, dy, idx, block: int):
                       dy_sel.astype(jnp.float32))
 
 
-def batched_dw_ref(x, dy, idx, block: int):
-    """Per-expert compact dW oracle: x: [E,C,K], dy: [E,C,N],
-    idx: [n_shards,n_sel] -> [E, K, n_shards, n_sel, block] fp32 (the
-    expert-batched compact-path layout; a dense per-expert einsum gathered
-    at the selection)."""
-    e, m, k = x.shape
+def batched_dw_ref(x, dy, group_sizes, idx, block: int):
+    """Per-expert compact dW oracle: x: [M,K], dy: [M,N] with rows sorted by
+    expert in groups of `group_sizes` [E] (rows past the last group belong
+    to none), idx: [n_shards,n_sel] -> [E, K, n_shards, n_sel, block] fp32
+    (the expert-batched compact-path layout; a dense per-expert einsum over
+    the expert's rows, gathered at the selection)."""
+    m, k = x.shape
     n = dy.shape[-1]
     n_shards, n_sel = idx.shape
-    dyb = dy.reshape(e, m, n_shards, n // (n_shards * block), block)
-    dy_sel = jnp.take_along_axis(dyb, idx[None, None, :, :, None], axis=3)
-    return jnp.einsum("eck,ecsjb->eksjb", x.astype(jnp.float32),
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(m)[None, :]
+    own = ((rows >= (ends - group_sizes)[:, None])
+           & (rows < ends[:, None])).astype(jnp.float32)        # [E, M]
+    dyb = dy.reshape(m, n_shards, n // (n_shards * block), block)
+    dy_sel = jnp.take_along_axis(dyb, idx[None, :, :, None], axis=2)
+    return jnp.einsum("em,mk,msjb->eksjb", own, x.astype(jnp.float32),
                       dy_sel.astype(jnp.float32))
 
 

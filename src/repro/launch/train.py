@@ -137,8 +137,11 @@ def main(argv=None):
 
     def on_metrics(step, metrics):
         if step % args.log_every == 0 or step == args.steps:
+            moe = (f" moe_rows_held={int(metrics['moe_rows_held'])} "
+                   f"moe_rows_max={int(metrics['moe_rows_max'])}"
+                   if cfg.moe is not None else "")
             print(f"[train] step {step:5d} loss={float(metrics['loss']):.4f} "
-                  f"ce={float(metrics['ce']):.4f}", flush=True)
+                  f"ce={float(metrics['ce']):.4f}{moe}", flush=True)
 
     losses, step_s = [], []
 

@@ -93,8 +93,18 @@ def _step_cache(cfg, kind: str, batch: int, seq_len: int):
     raise ValueError(kind)
 
 
+def check_servable(cfg) -> None:
+    """Raise a readable error for a model the decode paths cannot serve."""
+    if cfg.mla:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention (MLA) has no KV cache here (a "
+            f"latent paged cache is not built), so prefill and decode are "
+            f"not available for it; it trains through make_train_step")
+
+
 def init_cache(cfg, batch: int, seq_len: int):
     """Stacked caches per segment (leading axis = scan steps)."""
+    check_servable(cfg)
     cache = {}
     for seg in T.segment_layout(cfg):
         one = _step_cache(cfg, seg.kind, batch, seq_len)
@@ -269,6 +279,7 @@ def _serve_leaf(cfg, role: str, batch: int, max_len: int, kind: str,
 def init_serve_cache(cfg, batch: int, max_len: int, num_pages: int,
                      page_size: int):
     """Returns (state, pools): per-slot state tree + shared page pools."""
+    check_servable(cfg)
     pool_rows = num_pages * page_size
     state, pools = {}, {}
     for seg in T.segment_layout(cfg):

@@ -118,6 +118,8 @@ def apply_mrope(x, positions_thw, theta: float):
 # ---------------------------------------------------------------------------
 
 def init_attention(key, cfg, dtype):
+    if cfg.mla:
+        return init_mla(key, cfg, dtype)
     d, hd = cfg.d_model, cfg.resolved_head_dim
     kq, kk, kv, ko = jax.random.split(key, 4)
     return {
@@ -192,24 +194,43 @@ def _diag_mask(c: int, diag: int, window: int):
     return mask
 
 
+# The flash loops below are unrolled over the diagonals of blocks, and XLA
+# may schedule every diagonal's [b, nb, c, h, c] score blocks at once, and
+# share a rematerialized forward's with the backward: memory that grows as
+# the square of the diagonals (four fit beside a 2,048-token step; sixteen,
+# at 8,192 tokens, take over 5 GB). Past SERIAL_DIAGONALS each diagonal
+# starts only once the previous one has finished (an optimization barrier
+# over the carried state and the blocks it reads), so one diagonal's blocks
+# are live at a time.
+SERIAL_DIAGONALS = 4
+
+
+def _in_turn(n: int, *xs):
+    """xs unchanged, or held behind a barrier when the loop over n
+    diagonals runs them one at a time."""
+    return jax.lax.optimization_barrier(xs) if n > SERIAL_DIAGONALS else xs
+
+
 def _flash_fwd_impl(q, k, v, window: int, c: int):
     """Diagonal-block causal flash attention forward (pure jnp, online
     softmax). Only on/below-diagonal blocks are computed (no causal-FLOP
     waste); sliding windows statically truncate the diagonal range.
     Returns (out [b,s,h,d], lse [b,n,c,h])."""
     b, s, hq, dd = q.shape
+    dv = v.shape[-1]
     n = s // c
     qb = q.reshape(b, n, c, hq, dd)
     kb = k.reshape(b, n, c, hq, dd)
-    vb = v.reshape(b, n, c, hq, dd)
+    vb = v.reshape(b, n, c, hq, dv)
 
     scale = 1.0 / math.sqrt(dd)
     m = jnp.full((b, n, c, hq), -1e30, jnp.float32)    # running max
     l = jnp.zeros((b, n, c, hq), jnp.float32)           # running denom
-    o = jnp.zeros((b, n, c, hq, dd), jnp.float32)       # running numer
+    o = jnp.zeros((b, n, c, hq, dv), jnp.float32)       # running numer
 
     max_diag = n if not window else min(n, (window + c - 1) // c + 1)
     for diag in range(max_diag):
+        m, l, o, qb, kb, vb = _in_turn(n, m, l, o, qb, kb, vb)
         nb = n - diag                        # blocks on this diagonal
         qs = qb[:, diag:, ...]               # [b, nb, c, hq, dd]
         ks = kb[:, :nb, ...]
@@ -230,7 +251,7 @@ def _flash_fwd_impl(q, k, v, window: int, c: int):
         m = m.at[:, diag:, ...].set(m_new)
     out = o / jnp.maximum(l[..., None], 1e-30)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    return out.astype(q.dtype).reshape(b, s, hq, dd), lse
+    return out.astype(q.dtype).reshape(b, s, hq, dv), lse
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -249,22 +270,24 @@ def _flash_attn_bwd(window, c, res, dout):
     term at 4k+ sequence lengths; see EXPERIMENTS.md §Perf iteration 1)."""
     q, k, v, out, lse = res
     b, s, hq, dd = q.shape
+    dvh = v.shape[-1]
     n = s // c
     scale = 1.0 / math.sqrt(dd)
     qb = q.reshape(b, n, c, hq, dd)
     kb = k.reshape(b, n, c, hq, dd)
-    vb = v.reshape(b, n, c, hq, dd)
-    dob = dout.reshape(b, n, c, hq, dd)
-    ob = out.reshape(b, n, c, hq, dd)
+    vb = v.reshape(b, n, c, hq, dvh)
+    dob = dout.reshape(b, n, c, hq, dvh)
+    ob = out.reshape(b, n, c, hq, dvh)
     # delta_i = sum_d dout_i * out_i  (the softmax normalization term)
     delta = jnp.einsum("bnqhd,bnqhd->bnqh", dob.astype(jnp.float32),
                        ob.astype(jnp.float32))
 
     dq = jnp.zeros((b, n, c, hq, dd), jnp.float32)
     dk = jnp.zeros((b, n, c, hq, dd), jnp.float32)
-    dv = jnp.zeros((b, n, c, hq, dd), jnp.float32)
+    dv = jnp.zeros((b, n, c, hq, dvh), jnp.float32)
     max_diag = n if not window else min(n, (window + c - 1) // c + 1)
     for diag in range(max_diag):
+        dq, dk, dv, qb, kb, vb, dob = _in_turn(n, dq, dk, dv, qb, kb, vb, dob)
         nb = n - diag
         qs = qb[:, diag:, ...]
         ks = kb[:, :nb, ...]
@@ -286,7 +309,7 @@ def _flash_attn_bwd(window, c, res, dout):
             "bnqhk,bnkhd->bnqhd", ds, ks, preferred_element_type=jnp.float32))
         dk = dk.at[:, :nb].add(jnp.einsum(
             "bnqhk,bnqhd->bnkhd", ds, qs, preferred_element_type=jnp.float32))
-    rs = lambda t: t.reshape(b, s, hq, dd).astype(q.dtype)
+    rs = lambda t: t.reshape(b, s, hq, t.shape[-1]).astype(q.dtype)
     return rs(dq), rs(dk), rs(dv)
 
 
@@ -313,13 +336,61 @@ def attention(p, cfg, x, positions, *, window: int = 0, sel=None,
               flash_threshold: int = 2048):
     """Full training/prefill attention over a whole sequence."""
     b, s, _ = x.shape
-    q, k, v = _qkv(p, cfg, x, positions, sel=sel)
+    if cfg.mla:
+        q, k, v = _mla_qkv(p, cfg, x, positions, sel)
+    else:
+        q, k, v = _qkv(p, cfg, x, positions, sel=sel)
     if s > flash_threshold:
         out = _sdpa_flash(q, k, v, window)
     else:
         out = _sdpa_dense(q, k, v, window)
     out = out.reshape(b, s, -1)
     return smm(out, p["wo"], sel, "wo")
+
+
+def init_mla(key, cfg, dtype):
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA:
+    wq [d, H (dn + dr)], wkv_a [d, r + dr], kv_a_norm [r], wkv_b
+    [r, H (dn + dv)], wo [H dv, d] (dn, dr, dv: the nope, rope and value head
+    sizes; r: kv_lora_rank)."""
+    if cfg.q_lora_rank:
+        raise ValueError("latent attention with a query LoRA (q_lora_rank) "
+                         "is not built")
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(kq, (d, h * (dn + dr)), dtype=dtype),
+        "wkv_a": dense_init(ka, (d, r + dr), dtype=dtype),
+        "kv_a_norm": init_norm(ka, r, "rmsnorm", dtype),
+        "wkv_b": dense_init(kb, (r, h * (dn + dv)), dtype=dtype),
+        "wo": dense_init(ko, (h * dv, d), dtype=dtype),
+    }
+
+
+def _mla_qkv(p, cfg, x, positions, sel):
+    """Latent attention in its expanded form: the latent c_kv is decompressed
+    to per-head keys and values. q, k: [B, S, H, dn + dr] (nope dims, then
+    the rope dims, rotate-half RoPE; k's rope dims are one head shared by
+    all heads); v: [B, S, H, dv]. The key and value heads are `num_heads`
+    (`num_kv_heads` is not read)."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = smm(x, p["wq"], sel, "wq").reshape(b, s, h, dn + dr)
+    with jax.named_scope("latent_kv"):
+        kv_a = smm(x, p["wkv_a"], sel, "wkv_a")
+        c_kv = apply_norm(p["kv_a_norm"], kv_a[..., :r], cfg.norm_eps)
+        kv = smm(c_kv, p["wkv_b"], sel, "wkv_b").reshape(b, s, h, dn + dv)
+    k_pe = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, dr))], -1)
+    q = constrain(q, "batch", "seq", "heads", None)
+    k = constrain(k, "batch", "seq", "heads", None)
+    v = constrain(kv[..., dn:], "batch", "seq", "heads", None)
+    return q, k, v
 
 
 def decode_attention(p, cfg, x, positions, cache, *, window: int = 0):

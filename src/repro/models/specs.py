@@ -20,6 +20,10 @@ _RULES_2D = {
     "wk": ("model_d", "kv_heads"),
     "wv": ("model_d", "kv_heads"),
     "wo": ("heads", "model_d"),
+    # latent attention: the latent projection replicated, its per-head
+    # decompression column-parallel
+    "wkv_a": ("model_d", None),
+    "wkv_b": (None, "heads"),
     # mlp (column-parallel up/gate, row-parallel down)
     "w_gate": ("model_d", "ff"),
     "w_up": ("model_d", "ff"),

@@ -34,6 +34,22 @@ from repro.models.common import (dense_init, embed_init,
 from repro.sharding import constrain
 
 CE_CHUNK = 1024
+# a block's loss terms and counters, carried through the stacks as one
+# vector: the MoE balance and z terms and the slots on held experts add up
+# over layers, the largest held expert's rows is a max
+AUX = ("load_balance", "router_z", "rows_held", "rows_max")
+
+
+def _no_aux():
+    return jnp.zeros((len(AUX),), jnp.float32)
+
+
+def _aux_vec(aux: dict):
+    return jnp.stack([jnp.asarray(aux[k], jnp.float32) for k in AUX])
+
+
+def _aux_add(a, b):
+    return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
 
 
 class SegmentDef(NamedTuple):
@@ -143,8 +159,8 @@ def _init_rwkv_block(key, cfg, dtype):
 
 
 def _dense_ff_first(cfg) -> int:
-    # deepseek-style dense first layer: ~ (n_routed_active+shared) * d_ff
-    return 8 * cfg.d_ff
+    """The leading dense layer's width (deepseek-style layout)."""
+    return cfg.moe.dense_d_ff or 8 * cfg.d_ff
 
 
 def init_params(cfg: ModelConfig, key) -> dict:
@@ -214,32 +230,32 @@ def _sub_sel(sel, name):
 
 
 def _apply_dense_block(cfg, p, x, positions, sel, window: int):
-    h = L.apply_norm(p["attn_ln"], x)
+    h = L.apply_norm(p["attn_ln"], x, cfg.norm_eps)
     with jax.named_scope("token_mix"):
         x = x + L.attention(p["attn"], cfg, h, positions, window=window,
                             sel=_sub_sel(sel, "attn"))
-    h = L.apply_norm(p["mlp_ln"], x)
+    h = L.apply_norm(p["mlp_ln"], x, cfg.norm_eps)
     with jax.named_scope("channel_mix"):
         x = x + L.apply_mlp(p["mlp"], cfg, h, sel=_sub_sel(sel, "mlp"))
-    return x, jnp.zeros((2,), jnp.float32)
+    return x, _no_aux()
 
 
 def _apply_moe_block(cfg, p, x, positions, sel):
-    h = L.apply_norm(p["attn_ln"], x)
+    h = L.apply_norm(p["attn_ln"], x, cfg.norm_eps)
     with jax.named_scope("token_mix"):
         x = x + L.attention(p["attn"], cfg, h, positions,
                             sel=_sub_sel(sel, "attn"))
-    h = L.apply_norm(p["mlp_ln"], x)
+    h = L.apply_norm(p["mlp_ln"], x, cfg.norm_eps)
     with jax.named_scope("channel_mix"):
         y, aux = MOE.apply_moe(p["moe"], cfg, h, sel=_sub_sel(sel, "moe"))
         x = x + y
-    return x, jnp.stack([aux["load_balance"], aux["router_z"]])
+    return x, _aux_vec(aux)
 
 
 def _apply_jamba_super(cfg, p, x, positions, sel):
     period = cfg.attn_every
     attn_pos = period // 2
-    aux = jnp.zeros((2,), jnp.float32)
+    aux = _no_aux()
     for i in range(period):
         sub = p[f"sub{i}"]
         ssel = _sub_sel(sel, f"sub{i}")
@@ -257,7 +273,7 @@ def _apply_jamba_super(cfg, p, x, positions, sel):
             if _moe_at(cfg, i):
                 y, a = MOE.apply_moe(sub["moe"], cfg, h,
                                      sel=_sub_sel(ssel, "moe"))
-                aux = aux + jnp.stack([a["load_balance"], a["router_z"]])
+                aux = _aux_add(aux, _aux_vec(a))
             else:
                 y = L.apply_mlp(sub["mlp"], cfg, h, sel=_sub_sel(ssel, "mlp"))
             x = x + y
@@ -270,7 +286,7 @@ def _apply_gemma_super(cfg, p, x, positions, sel, period: int):
         window = _window_for(cfg, "gemma_super", i)
         x, _ = _apply_dense_block(cfg, sub, x, positions,
                                   _sub_sel(sel, f"sub{i}"), window)
-    return x, jnp.zeros((2,), jnp.float32)
+    return x, _no_aux()
 
 
 def _apply_rwkv_block(cfg, p, x, positions, sel):
@@ -283,7 +299,7 @@ def _apply_rwkv_block(cfg, p, x, positions, sel):
         y, _ = R.apply_channel_mix(p["chan"], cfg, h,
                                    sel=_sub_sel(sel, "chan"))
         x = x + y
-    return x, jnp.zeros((2,), jnp.float32)
+    return x, _no_aux()
 
 
 def _apply_step(cfg, kind: str, p, x, positions, sel):
@@ -307,10 +323,13 @@ def _run_segment(cfg, kind: str, stack, x, positions, sel_idx, sel_spec,
     """Scan a segment. sel_idx: stacked [steps, ...] idx tree or None.
     sel_wsel: stacked compact selected-block tree (compact-gradient path)."""
     if stack is None:
-        return x, jnp.zeros((2,), jnp.float32)
+        return x, _no_aux()
+
+    # only MoE blocks have loss terms and counters to carry
+    carries = kind in ("moe", "jamba_super")
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux = carry if carries else (carry, None)
         p_l, idx_l, wsel_l = xs
         if idx_l is None:
             sel = None
@@ -320,14 +339,14 @@ def _run_segment(cfg, kind: str, stack, x, positions, sel_idx, sel_spec,
             sel = (idx_l, sel_spec, wsel_l)
         x = constrain(x, "batch", "seq", "model_d")
         x, a = _apply_step(cfg, kind, p_l, x, positions, sel)
-        return (x, aux + a), None
+        return ((x, _aux_add(aux, a)) if carries else x), None
 
     fn = jax.checkpoint(body) if remat else body
     steps = jax.tree.leaves(stack)[0].shape[0]
     xs = (stack, sel_idx, sel_wsel)
-    (x, aux), _ = jax.lax.scan(fn, (x, jnp.zeros((2,), jnp.float32)), xs,
-                               length=steps)
-    return x, aux
+    out, _ = jax.lax.scan(fn, (x, _no_aux()) if carries else x, xs,
+                          length=steps)
+    return out if carries else (out, _no_aux())
 
 
 # ---------------------------------------------------------------------------
@@ -377,7 +396,7 @@ def forward(cfg, params_pair, batch, sel=None, remat: bool = True):
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
 
-    aux = jnp.zeros((2,), jnp.float32)
+    aux = _no_aux()
     for seg in segment_layout(cfg):
         f_stack = _pick(frozen, None, "segments", seg.name)
         t_stack = _pick(trainable, None, "segments", seg.name)
@@ -392,9 +411,10 @@ def forward(cfg, params_pair, batch, sel=None, remat: bool = True):
         with jax.named_scope("trainable_layers"):
             x, a2 = _run_segment(cfg, seg.kind, t_stack, x, positions,
                                  sel_idx, sel_spec, remat, sel_wsel=sel_wsel)
-        aux = aux + a1 + a2
+        aux = _aux_add(_aux_add(aux, a1), a2)
     with jax.named_scope("head_loss"):   # the final norm feeds only the head
-        x = L.apply_norm(_pick(frozen, trainable, "final_norm"), x)
+        x = L.apply_norm(_pick(frozen, trainable, "final_norm"), x,
+                         cfg.norm_eps)
     return x, aux
 
 
@@ -428,12 +448,18 @@ def chunked_cross_entropy(hidden, w_head, labels, chunk: int = CE_CHUNK):
     return total, b * s
 
 
-def loss_fn(cfg, params_pair, batch, sel=None, remat: bool = True,
-            aux_weight: float = 0.01, z_weight: float = 1e-3):
+def loss_fn(cfg, params_pair, batch, sel=None, remat: bool = True):
+    """(loss, metrics): the mean cross-entropy plus, for an MoE model, the
+    router terms at `cfg.moe`'s weights; the metrics add the MoE counters
+    `moe_rows_held` (routing slots on held experts, over layers) and
+    `moe_rows_max` (the largest held expert's rows in any layer)."""
     hidden, aux = forward(cfg, params_pair, batch, sel=sel, remat=remat)
     with jax.named_scope("head_loss"):
         w_head = lm_head_weight(cfg, params_pair)
         total, count = chunked_cross_entropy(hidden, w_head, batch["labels"])
         ce = total / count
-    loss = ce + aux_weight * aux[0] + z_weight * aux[1]
-    return loss, {"ce": ce, "load_balance": aux[0], "router_z": aux[1]}
+    loss = ce
+    if cfg.moe is not None:
+        loss = loss + cfg.moe.aux_weight * aux[0] + cfg.moe.z_weight * aux[1]
+    return loss, {"ce": ce, "load_balance": aux[0], "router_z": aux[1],
+                  "moe_rows_held": aux[2], "moe_rows_max": aux[3]}

@@ -223,6 +223,7 @@ class ServeEngine:
                  watchdog_s: Optional[float] = None,
                  journal=None, straggler_factor: float = 2.5,
                  rules=None, flash_decode: Optional[bool] = None):
+        D.check_servable(cfg)
         assert num_slots >= 1 and max_len >= 2 and page_size >= 1
         assert prefix_mode in ("radix", "chain", "off")
         assert max_retries >= 0 and 0.0 <= shed_watermark < 1.0

@@ -13,8 +13,7 @@ import pytest
 from repro.testing import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.batched_dw import (batched_dw_kernel,
-                                      batched_dw_pipelined_kernel)
+from repro.kernels.batched_dw import batched_dw_kernel
 from repro.kernels.block_act_prune import block_act_prune_kernel
 from repro.kernels.fused_block_opt import fused_block_opt_kernel
 from repro.kernels.masked_dw import (block_sparse_dw_kernel,
@@ -98,9 +97,19 @@ def test_block_sparse_dw_pipelined_sweep(variant, n_shards, m, k, nb, block,
                                rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("variant", [batched_dw_kernel,
-                                     batched_dw_pipelined_kernel],
-                         ids=["grid", "pipelined"])
+def _group_sizes(rng, layout: str, n_experts: int, m: int):
+    """Rows per expert over m rows: "uneven" leaves some experts empty and
+    the last rows in no group; "skewed" gives every row to the last
+    expert."""
+    if layout == "skewed":
+        return jnp.asarray([0] * (n_experts - 1) + [m], jnp.int32)
+    cuts = np.sort(rng.integers(0, m - m // 4, n_experts - 1))
+    sizes = np.diff(np.concatenate([[0], cuts, [m - m // 4]]))
+    sizes[0] = 0
+    return jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.mark.parametrize("layout", ["uneven", "skewed"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n_experts", [2, 4])
 @pytest.mark.parametrize("n_shards", [1, 2])
@@ -109,19 +118,23 @@ def test_block_sparse_dw_pipelined_sweep(variant, n_shards, m, k, nb, block,
     (16, 16, 6, 8, 5, 16, 16),        # odd n_sel
     (64, 32, 3, 32, 2, 32, 32),
 ])
-def test_batched_dw_sweep(variant, dtype, n_experts, n_shards, c, k, nb,
+def test_batched_dw_sweep(layout, dtype, n_experts, n_shards, c, k, nb,
                           block, n_sel, tm, tk):
-    """Expert-batched compact dW (one launch over experts x shards x
-    selected blocks) vs the per-expert jnp einsum oracle, grid AND
-    pipelined variants."""
+    """Expert-batched compact dW over rows grouped by expert (one launch
+    over experts x shards x selected blocks, a visit per row tile of each
+    expert) vs the per-expert jnp einsum oracle, with empty experts, tiles
+    shared by two experts and rows past the last group."""
     rng = np.random.default_rng(c * 3 + nb * n_shards + n_experts)
     n = n_shards * nb * block
-    x = jnp.asarray(rng.normal(size=(n_experts, c, k)), dtype)
-    dy = jnp.asarray(rng.normal(size=(n_experts, c, n)), dtype)
+    m = n_experts * c
+    x = jnp.asarray(rng.normal(size=(m, k)), dtype)
+    dy = jnp.asarray(rng.normal(size=(m, n)), dtype)
+    sizes = _group_sizes(rng, layout, n_experts, m)
     idx = _sel_idx(rng, (n_shards,), nb, n_sel)
-    out = variant(x, dy, idx, block=block, tm=tm, tk=tk, interpret=True)
+    out = batched_dw_kernel(x, dy, sizes, idx, block=block, tm=tm, tk=tk,
+                            interpret=True)
     assert out.shape == (n_experts, k, n_shards, n_sel, block)
-    want = ref.batched_dw_ref(x, dy, idx, block)
+    want = ref.batched_dw_ref(x, dy, sizes, idx, block)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -133,17 +146,18 @@ def test_batched_dw_deselected_expert_blocks_frozen():
     kernel in the backward and the fused optimizer applied on the stacked
     expert leaf, the DESELECTED blocks of every expert — weights and
     optimizer state — come back bitwise untouched."""
-    from repro.core.sparse_update import SelSpec, smm, use_kernels
+    from repro.core.sparse_update import SelSpec, gmm, use_kernels
     rng = np.random.default_rng(7)
     e, c, k, s, nb, blk, n_sel = 3, 16, 16, 2, 4, 8, 1
     n = s * nb * blk
     spec = SelSpec(block=blk, n_shards=s, n_sel=n_sel, n_blocks=nb)
-    x = jnp.asarray(rng.normal(size=(e, c, k)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(e * c, k)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(e, k, n)), jnp.float32)
+    sizes = jnp.full((e,), c, jnp.int32)
     idx = _sel_idx(rng, (s,), nb, n_sel)
     sel = ({"w": idx}, {"w": spec})
     with use_kernels(True):
-        dw = jax.grad(lambda w: (smm(x, w, sel, "w") ** 2).sum())(w)
+        dw = jax.grad(lambda w: (gmm(x, w, sizes, sel, "w") ** 2).sum())(w)
     sel_mask = np.zeros((e, k, s, nb, blk), bool)
     for si in range(s):
         sel_mask[:, :, si, np.asarray(idx)[si], :] = True
@@ -373,3 +387,50 @@ def test_wkv6_chunk_kernel(chunk, bh, t, d):
     want = ref.wkv6_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("lanes", [1, 128])
+def test_ops_pad_a_block_of_part_lanes(monkeypatch, lanes):
+    """A channel block of 96 (a 576-wide latent projection's) through every
+    compact-path kernel entry, unpadded and padded to 128 lanes as when
+    compiling for a TPU: the same dW, expert dW, writeback and AdamW
+    update as the jnp path."""
+    from repro.configs.base import OptimizerConfig
+    from repro.core.sparse_update import (SelSpec, compact_dw,
+                                          compact_dw_batched,
+                                          scatter_param_blocks)
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "lanes", lambda: lanes)
+    rng = np.random.default_rng(11)
+    m, k, blk, nb, n_sel = 64, 128, 96, 6, 2
+    spec = SelSpec(block=blk, n_shards=1, n_sel=n_sel, n_blocks=nb)
+    idx = jnp.asarray([[4, 1]], jnp.int32)
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(m, nb * blk)), jnp.float32)
+    sizes = jnp.asarray([20, 0, 30], jnp.int32)
+    np.testing.assert_allclose(ops.block_sparse_dw(x, dy, idx, spec),
+                               compact_dw(x, dy, idx, spec),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(
+        ops.block_sparse_dw_batched(x, dy, sizes, idx, spec),
+        compact_dw_batched(x, dy, sizes, idx, spec), rtol=1e-5, atol=1e-4)
+
+    w = jnp.asarray(rng.normal(size=(2, k, nb * blk)), jnp.float32)
+    kidx = jnp.asarray([[[4, 1]], [[0, 5]]], jnp.int32)
+    vals = jnp.asarray(rng.normal(size=(2, k, 1, n_sel, blk)), jnp.float32)
+    np.testing.assert_array_equal(
+        ops.block_scatter_update(w, vals, kidx, spec),
+        scatter_param_blocks(w, vals, kidx, spec))
+
+    oc = OptimizerConfig(kind="adamw", learning_rate=1e-2, weight_decay=0.1)
+    mu = jnp.asarray(rng.normal(size=w.shape) * 0.1, jnp.float32)
+    nu = jnp.asarray(rng.uniform(size=w.shape) * 0.1, jnp.float32)
+    got = ops.fused_block_optimizer(oc, w, vals, kidx, spec, mu, nu,
+                                    jnp.float32(1e-2), jnp.float32(3))
+    want = ref.fused_block_opt_ref(w, vals, kidx, jnp.float32(1e-2),
+                                   jnp.float32(3), mu, nu, kind="adamw",
+                                   momentum=oc.momentum, beta1=oc.beta1,
+                                   beta2=oc.beta2, eps=oc.eps,
+                                   weight_decay=oc.weight_decay)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)

@@ -110,9 +110,6 @@ def test_train_decreases_loss_dense_vs_sparse():
                                   "qwen2-vl-7b"])
 def test_prefill_decode_matches_forward(arch):
     cfg = get_smoke_config(arch)
-    if cfg.moe is not None:  # disable token dropping for exactness
-        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-            cfg.moe, capacity_factor=8.0))
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     b, s = 2, 24
     key = jax.random.PRNGKey(1)
@@ -190,6 +187,49 @@ def test_moe_aux_losses_and_balance():
     assert y.shape == x.shape
     assert float(aux["load_balance"]) >= 1.0 - 1e-3  # >= 1 by Cauchy-Schwarz
     assert bool(jnp.isfinite(y).all())
+
+
+@pytest.mark.parametrize("chunk", [8, 24, 1024])
+def test_held_experts_live_rows_match_dense(monkeypatch, chunk):
+    """The dropless dispatch's loops over the live rows (chunks of `chunk`
+    rows, the last one part-filled) against every held expert applied to
+    every token under its gate: the output and the gradients of the
+    tokens, the gates and the expert weights."""
+    from repro.models import moe as MOE
+    monkeypatch.setattr(MOE, "CHUNK", chunk)
+    t, d, ff, e_h, routed, k, first = 40, 16, 24, 4, 12, 3, 4
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(ks[0], (t, d))
+    ids = jax.vmap(lambda kk: jax.random.permutation(kk, routed)[:k])(
+        jax.random.split(ks[1], t))
+    w = jax.random.uniform(ks[2], (t, k), minval=0.1, maxval=1.0)
+    wp = {n: jax.random.normal(kk, s) * 0.3 for n, kk, s in (
+        ("w_gate", ks[3], (e_h, d, ff)), ("w_up", ks[4], (e_h, d, ff)),
+        ("w_down", ks[5], (e_h, ff, d)))}
+
+    def program(x, w, wp):
+        y, sizes = MOE.held_experts(wp, x, ids, w, first, None)
+        return y, sizes
+
+    def dense(x, w, wp):
+        y = jnp.zeros((t, d))
+        for i in range(e_h):
+            gate = jnp.sum(jnp.where(ids == first + i, w, 0.0), axis=1)
+            h = jax.nn.silu(x @ wp["w_gate"][i]) * (x @ wp["w_up"][i])
+            y = y + gate[:, None] * (h @ wp["w_down"][i])
+        return y
+
+    y, sizes = program(x, w, wp)
+    held = (ids >= first) & (ids < first + e_h)
+    assert int(sizes.sum()) == int(held.sum())
+    np.testing.assert_allclose(y, dense(x, w, wp), rtol=1e-4, atol=1e-5)
+    probe = jax.random.normal(jax.random.PRNGKey(9), (t, d))
+    loss = lambda f: lambda *a: jnp.sum(probe * (f(*a)[0] if f is program
+                                                 else f(*a)))
+    got = jax.grad(loss(program), argnums=(0, 1, 2))(x, w, wp)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(x, w, wp)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
 def test_mamba_chunked_scan_matches_stepwise():
@@ -346,3 +386,34 @@ def test_mobilenet_smoke():
     logits = MN.forward(cfg, (params, None), imgs)
     assert logits.shape == (2, cfg.num_classes)
     assert bool(jnp.isfinite(logits).all())
+
+
+def test_latent_attention_model_refuses_serving():
+    """An MLA model trains but has no KV cache: the decode paths and the
+    engine say so, and the dry-run skips its prefill and decode shapes."""
+    from repro.configs import cell_is_skipped
+    from repro.models import decoding as D
+    from repro.serve.engine import ServeEngine
+    cfg = get_smoke_config("moonlight-16b-a3b")
+    for build in (lambda: D.init_cache(cfg, 1, 32),
+                  lambda: D.init_serve_cache(cfg, 1, 32, 4, 8),
+                  lambda: ServeEngine(cfg, None, num_slots=1, max_len=16)):
+        with pytest.raises(NotImplementedError, match="latent attention"):
+            build()
+    for shape in ("prefill_32k", "decode_32k"):
+        assert "latent attention" in cell_is_skipped("moonlight-16b-a3b",
+                                                     shape)
+    assert cell_is_skipped("moonlight-16b-a3b", "train_4k") is None
+
+
+@pytest.mark.parametrize("arch,smoke,width", [
+    ("deepseek-moe-16b", False, 10_944), ("moonlight-16b-a3b", False, 11_264),
+    ("deepseek-moe-16b", True, 8 * 32)])
+def test_dense_first_layer_width(arch, smoke, width):
+    """The leading dense layer's width is the config's `dense_d_ff` where
+    the model publishes one, else 8 d_ff."""
+    from repro.configs import get_config
+    from repro.models.registry import abstract_params
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    segs = abstract_params(cfg)["segments"]
+    assert segs["first"]["mlp"]["w_gate"].shape[-1] == width

@@ -136,8 +136,7 @@ mesh = make_debug_mesh(2, 4)
 
 # --- sharded MoE == local MoE -------------------------------------------
 cfg = get_smoke_config("deepseek-moe-16b")
-cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, num_experts=8,
-                                                       capacity_factor=8.0))
+cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, num_experts=8))
 params = T.init_params(cfg, jax.random.PRNGKey(0))
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size),
          "labels": jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, cfg.vocab_size)}

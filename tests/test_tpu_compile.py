@@ -5,16 +5,18 @@ Interpret mode (every other kernel test) never applies the chip's tiling
 and memory rules; Mosaic does, here. Widths: 4,096 tokens per step
 (batch 4 x seq 1024), d_model 2560, d_ff 8960, channel block 128, update
 ratio 0.2 (4 of 20 blocks of a 2560-wide leaf, 14 of 70 of an 8960-wide
-one), 2 trainable layers. Each test asserts the compiled HLO holds the
-kernel as exactly one `tpu_custom_call`.
+one), 2 trainable layers; the expert kernel at moonlight-16b-a3b-ep8's
+(16,384 tokens, top-6 of 64 experts, 8 held: a dispatch bound of 98,304
+rows; d_model 2048, expert width 1408). Each test asserts the compiled HLO
+holds the kernel as exactly one `tpu_custom_call`.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.batched_dw import (batched_dw_kernel,
-                                      batched_dw_pipelined_kernel)
+from repro.kernels import ops
+from repro.kernels.batched_dw import batched_dw_kernel
 from repro.kernels.fused_block_opt import fused_block_opt_kernel
 from repro.kernels.masked_dw import (block_sparse_dw_kernel,
                                      block_sparse_dw_pipelined_kernel)
@@ -71,13 +73,16 @@ def test_masked_dw_compiles(v5e, k, n, n_sel, pipelined):
         ((M, k), BF), ((M, n), BF), ((1, n_sel), I32)))
 
 
-@pytest.mark.parametrize("pipelined", [False, True], ids=["grid", "pipelined"])
-def test_batched_dw_compiles(v5e, pipelined):
-    # the step's 4,096 tokens dispatched over 4 experts of capacity 1,024
-    kern = batched_dw_pipelined_kernel if pipelined else batched_dw_kernel
+@pytest.mark.parametrize("k,n,n_sel", [(2048, 1408, 2), (1408, 2048, 3)],
+                         ids=["gate_up", "down"])
+def test_batched_dw_compiles(v5e, k, n, n_sel):
+    # the expert leaves of moonlight-16b-a3b-ep8, at the tiles ops picks
+    rows = 98_304
     _assert_one_kernel(_compile(
-        v5e, lambda x, dy, idx: kern(x, dy, idx, block=BLOCK),
-        ((4, M // 4, D), BF), ((4, M // 4, D), BF), ((1, 4), I32)))
+        v5e, lambda x, dy, sizes, idx: batched_dw_kernel(
+            x, dy, sizes, idx, block=BLOCK, tm=ops._pick_tile(rows, 512),
+            tk=ops._lane_tile(k, 2048)),
+        ((rows, k), BF), ((rows, n), BF), ((8,), I32), ((1, n_sel), I32)))
 
 
 def test_scatter_blocks_compiles(v5e):
